@@ -23,8 +23,7 @@ CAPI_SO     := lib/libspfft_tpu.so
 # local test matrix): full CPU suite (8-device virtual mesh; includes the
 # capi build, C feature drive, Fortran-width execution and the in-suite
 # multihost smoke), the compiled C example, the standalone 2-process
-# multihost smoke, and the precision matrix in CPU mode. Record with
-#   make ci 2>&1 | tee docs/ci_r05.log
+# multihost smoke, and the precision matrix in CPU mode.
 ci: native capi
 	@echo "== CI 1/4: test suite (CPU, virtual 8-device mesh) =="
 	python -m pytest tests/ -q
@@ -72,8 +71,8 @@ analyze:
 # axes and repeated-backward — the silent-corruption bug classes the
 # CPU-pinned suite cannot see — plus the serving smokes (pinning +
 # fault-injection: bucket isolation, device quarantine over the real
-# chip pool, crash-proof dispatch). Needs the real chip; record with
-#   make ci-tpu 2>&1 | tee docs/ci_tpu_r05.log
+# chip pool, crash-proof dispatch). Needs the real chip (through the
+# chip tool: one process owns the chip).
 # lint + analyze + chaos-smoke + cluster-smoke + pod-smoke run first:
 # the chip lane is expensive, so it never starts on a tree the static
 # passes already know is dirty or whose failure semantics the CPU

@@ -14,6 +14,7 @@ Set ``SPFFT_TPU_NO_NATIVE=1`` to force the NumPy fallback.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import sys
@@ -25,6 +26,8 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "planner.cpp")
 _LIB = os.path.join(_DIR, f"_planner_{sys.implementation.cache_tag}.so")
+
+logger = logging.getLogger("spfft_tpu")
 
 _lock = threading.Lock()
 #: guarded by _lock
@@ -106,8 +109,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 # symbol) — rebuild once before giving up.
                 _compile()
                 _lib = _bind(ctypes.CDLL(_LIB))
-        except (OSError, AttributeError, subprocess.CalledProcessError):
+        except (OSError, AttributeError, subprocess.CalledProcessError) as e:
             _load_failed = True
+            logger.warning("spfft_tpu: native planner unavailable (%s) — "
+                           "plan-time index work falls back to NumPy",
+                           getattr(e, "stderr", None) or e)
     return _lib  # lock: waived(post-with read - either the handle this call published or another loader's, both final)
 
 

@@ -74,12 +74,12 @@ def max_dim() -> int:
     return min(_EMPIRICAL_MAX, dft.MATMUL_DFT_MAX)
 
 #: Per-kernel VMEM budget (bytes) the single-stage tile chooser aims
-#: under. The EMPIRICAL compile ceiling on v5e is ~5.5 MB by the
-#: footprint formula (tm sweep at 384/512: 5.2 MB compiles, 7.3 MB
-#: crashes the compile helper — Mosaic's double-buffering of streamed
-#: tiles and dot accumulators roughly doubles the formula), so both
-#: budgets sit just under it. 256-class stages keep tm=1024 (5.0 MB);
-#: 384 -> tm=512, 512 -> tm=256.
+#: under. The compile ceiling on v5e is ~5.5 MB by the footprint
+#: formula: Mosaic's double-buffering of streamed tiles and dot
+#: accumulators roughly doubles it. The installed compiler accepts the
+#: tiles this budget picks (256-class tm=1024, 384 -> tm=512,
+#: 512 -> tm=256) and runs out of VMEM on the next tile up at 384 and
+#: 512 (tests/test_tpu_compile.py::test_stage_kernel_compiles).
 _VMEM_BUDGET = int(5.5 * 1024 * 1024)
 
 

@@ -17,14 +17,16 @@ them, in both directions. These kernels close that gap:
   ``(r_sticks, dim_z)`` and contracted against the resident Karatsuba
   DFT matrices, and only the *transformed* planar block is written.
   The dense pre-FFT stick intermediate never touches HBM.
-* :func:`run_zdft_compress` (forward twin): each grid step DMAs the
-  RAW stick rows covering its chunk's source window, z-transforms them
-  in VMEM (any FULL scaling folded into the matrices at plan time —
-  compile-time scaling, zero extra passes), slices the transformed
-  window out of the flat slot layout, and runs the windowed compress
-  gather against it. The transformed stick array never touches HBM;
-  the cost is a bounded DFT recompute where windows overlap, which the
-  plan-time cost model gates (:func:`compress_recompute_rows`).
+* :func:`run_zdft_compress` (forward twin): chunks are grouped into
+  8-stick-aligned RAW stick windows of :func:`_win_sticks` rows
+  (Mosaic only DMAs whole 8-row tiles of a (sticks, dim_z) array).
+  The first chunk of a window DMAs and z-transforms it in VMEM (any
+  FULL scaling folded into the matrices at plan time); every chunk
+  then slices its K-row source window out of the transformed flat
+  slot layout and runs the windowed compress gather against it. The
+  transformed stick array never touches HBM; the cost is a bounded DFT
+  recompute where consecutive windows overlap, which the plan-time
+  cost model gates (:func:`compress_recompute_rows`).
 
 Geometry: tables reuse the NARROW gather decomposition (chunks of one
 1024-slot tile; chunks of a tile are consecutive grid steps). A fused
@@ -44,8 +46,8 @@ path — same math, same layouts — with the reason recorded through
 ``obs`` (``spfft_plan_pallas_fallback_total``).
 
 ``SPFFT_TPU_FUSED_COMPRESS=0`` disables the fused path;
-``SPFFT_TPU_FUSED_INTERPRET=1`` forces interpret-mode execution (and
-activation off-TPU) for the CPU A/B lane (``benchmark.py --fused``,
+``SPFFT_TPU_FUSED_INTERPRET=1`` activates it off-TPU, in interpret
+mode, for the CPU A/B lane (``benchmark.py --fused``,
 ``make fused-smoke``).
 """
 
@@ -115,9 +117,9 @@ def recompute_limit() -> float:
             pass
     return RECOMPUTE_LIMIT
 
-#: Per-kernel VMEM budget the geometry chooser stays under — matches
-#: the single-stage DFT kernel's empirically-calibrated ceiling
-#: (ops.dft_kernel._VMEM_BUDGET rationale).
+#: Per-kernel VMEM budget the geometry chooser stays under — the
+#: single-stage DFT kernel's ceiling (ops.dft_kernel._VMEM_BUDGET); the
+#: 256^3 fused kernels compile under it (tests/test_tpu_compile.py).
 _VMEM_BUDGET = int(5.5 * 1024 * 1024)
 
 
@@ -129,10 +131,11 @@ def enabled() -> bool:
 
 
 def interpret_forced() -> bool:
-    """``SPFFT_TPU_FUSED_INTERPRET=1`` runs the fused kernels in
-    interpret mode and activates them off-TPU — the CPU A/B and smoke
+    """``SPFFT_TPU_FUSED_INTERPRET=1`` activates the fused kernels
+    off-TPU, where they run in interpret mode — the CPU A/B and smoke
     lane (numbers there are honest overhead-only, like the overlap
-    round's CPU A/B)."""
+    round's CPU A/B). On a TPU backend the kernels always compile for
+    the chip; the variable changes nothing there."""
     return os.environ.get("SPFFT_TPU_FUSED_INTERPRET", "").strip() == "1"
 
 
@@ -175,7 +178,7 @@ def _fits_backward(dim_z: int, p_tiles: int, span_rows: int,
 
 def _fits_forward(dim_z: int, win_sticks: int, span_rows: int) -> bool:
     mats = 3 * dim_z * dim_z
-    window = 2 * 2 * win_sticks * dim_z          # raw-stick DMA buffers
+    window = 2 * win_sticks * dim_z              # raw-stick DMA buffer
     work = 6 * win_sticks * dim_z                # transformed + flat views
     out = 2 * 2 * TILE
     return (mats + window + work + out) * 4 <= _VMEM_BUDGET
@@ -219,14 +222,17 @@ class FusedCompressTables:
     source windows re-expressed as RAW STICK ranges (the kernel
     transforms them in VMEM before gathering)."""
 
-    s0: np.ndarray       # (C,) int32 — first raw stick of the window DMA
+    s0: np.ndarray       # (C,) int32 — first raw stick of the chunk's
+                         # stick window (a multiple of 8)
     off: np.ndarray      # (C,) int32 — transformed-window start row,
                          # relative to s0, in the flat (rows, 128) layout
     out_tile: np.ndarray  # (C,) int32 — output value tile (as narrow)
     first: np.ndarray    # (C,) int32 — 1 on a tile's first chunk
+    wnew: np.ndarray     # (C,) int32 — 1 on a stick window's first chunk
+                         # (DMA + z-transform); 0 reuses the window
     packed: np.ndarray   # (C, 8, 128) int32 — narrow selector words
     dim_z: int
-    win_sticks: int      # S_w: raw sticks DMA'd per chunk
+    win_sticks: int      # S_w: raw sticks per stick window
     num_tiles: int       # output value tiles
     num_out: int         # valid output slots
     src_sticks: int      # padded raw-stick rows the source must carry
@@ -274,12 +280,51 @@ def build_fused_decompress_tables(t: MonotoneGatherTables, dim_z: int,
         src_rows=t.src_rows, span_rows=t.span_rows, zinfo=zinfo)
 
 
+#: Minimum raw sticks per forward stick window. Consecutive chunks
+#: whose source windows fit one stick window share its DMA and
+#: z-transform, so a taller window transforms fewer sticks twice: at
+#: the 256^3 sphere the cost model's recompute factor is 5.5 with
+#: per-chunk 24-stick windows and 1.0 with shared 48-stick ones (1.5 at
+#: 128^3, 1.25 at 320^3).
+FWD_WIN_STICKS = 48
+
+
+def _win_sticks(span_rows: int, dim_z: int) -> int:
+    """Raw sticks per forward stick window: a whole number of 8-row
+    tiles, since Mosaic refuses a DMA slice of an (8, 128)-tiled
+    (sticks, dim_z) array whose start or height is not a multiple of 8
+    (tests/test_tpu_compile.py), and tall enough to hold any chunk's
+    K-row source window after its start is rounded down to a tile."""
+    q = dim_z // TILE_LANE
+    need = -(-(span_rows + q - 1) // q) + TILE_SUB - 1
+    return max(-(-need // TILE_SUB) * TILE_SUB, FWD_WIN_STICKS)
+
+
+def _stick_windows(t: MonotoneGatherTables, dim_z: int):
+    """Greedy cover of the chunks' source windows by stick windows, in
+    chunk order: a chunk reuses the current window when its K flat rows
+    fit inside it, else opens a new one at its first stick rounded down
+    to a tile. Returns per-chunk (s0, off, wnew) and the window count."""
+    q = dim_z // TILE_LANE
+    w = _win_sticks(t.span_rows, dim_z)
+    row0 = t.row0.astype(np.int64)
+    lo = row0 // q
+    hi = (row0 + t.span_rows + q - 1) // q      # exclusive stick bound
+    s0 = np.empty_like(row0)
+    wnew = np.zeros(row0.shape[0], np.int32)
+    cur = -1
+    for g in range(row0.shape[0]):
+        if cur < 0 or lo[g] < cur or hi[g] > cur + w:
+            cur = int(lo[g]) // TILE_SUB * TILE_SUB
+            wnew[g] = 1
+        s0[g] = cur
+    return s0, row0 - s0 * q, wnew, int(wnew.sum())
+
+
 def compress_recompute_rows(t: MonotoneGatherTables, dim_z: int) -> int:
     """Stick rows the fused forward would z-transform in total (each
-    chunk transforms its whole window) — the cost model's numerator."""
-    q = dim_z // TILE_LANE
-    win_sticks = -(-t.span_rows // q) + 1
-    return int(t.row0.shape[0]) * win_sticks
+    stick window is transformed once) — the cost model's numerator."""
+    return _stick_windows(t, dim_z)[3] * _win_sticks(t.span_rows, dim_z)
 
 
 def build_fused_compress_tables(t: MonotoneGatherTables, dim_z: int,
@@ -294,16 +339,14 @@ def build_fused_compress_tables(t: MonotoneGatherTables, dim_z: int,
     if t.segs:
         return "segmented"
     q = dim_z // TILE_LANE
-    win_sticks = -(-t.span_rows // q) + 1
+    win_sticks = _win_sticks(t.span_rows, dim_z)
     if not _fits_forward(dim_z, win_sticks, t.span_rows):
         return "vmem"
-    if compress_recompute_rows(t, dim_z) > recompute_limit() \
-            * max(int(num_sticks), 1):
+    s0, off, wnew, n_win = _stick_windows(t, dim_z)
+    if n_win * win_sticks > recompute_limit() * max(int(num_sticks), 1):
         return "recompute_blowup"
     # window rows [row0, row0+K) of the flat (rows, 128) transformed
     # layout live inside raw sticks [s0, s0 + win_sticks)
-    s0 = (t.row0.astype(np.int64) * TILE_LANE) // dim_z
-    off = t.row0.astype(np.int64) - s0 * q
     assert int((off + t.span_rows).max(initial=0)) <= win_sticks * q
     # the DMA always reads the STATIC win_sticks rows from s0, so the
     # source must be padded to the furthest row any window's DMA touches
@@ -311,7 +354,7 @@ def build_fused_compress_tables(t: MonotoneGatherTables, dim_z: int,
                      int(num_sticks))
     return FusedCompressTables(
         s0=s0.astype(np.int32), off=off.astype(np.int32),
-        out_tile=t.out_tile, first=t.first, packed=t.packed,
+        out_tile=t.out_tile, first=t.first, wnew=wnew, packed=t.packed,
         dim_z=int(dim_z), win_sticks=win_sticks,
         num_tiles=t.num_tiles, num_out=t.num_out,
         src_sticks=src_sticks, span_rows=t.span_rows)
@@ -334,7 +377,7 @@ def compress_device_tables(t: FusedCompressTables) -> tuple:
     """Device-committed table tuple for :func:`run_zdft_compress`."""
     return (jnp.asarray(t.s0), jnp.asarray(t.off),
             jnp.asarray(t.out_tile), jnp.asarray(t.first),
-            jnp.asarray(t.packed))
+            jnp.asarray(t.wnew), jnp.asarray(t.packed))
 
 
 def commit_mats(mats) -> tuple:
@@ -358,8 +401,11 @@ def _complete_zero_stick(R, dz, xr, xi, is_z, z_row):
     paths stay bit-identical. ``is_z`` (this super-tile owns the zero
     stick) and ``z_row`` arrive as DATA, not trace constants, so one
     compiled program serves every shard of a distributed plan."""
-    row_r = jax.lax.dynamic_slice_in_dim(xr, z_row, 1, 0)   # (1, dz)
-    row_i = jax.lax.dynamic_slice_in_dim(xi, z_row, 1, 0)
+    # row z_row as a masked sublane reduction: Mosaic has no lowering
+    # for dynamic_slice on a value, and adding zeros is exact
+    zsel = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) == z_row
+    row_r = jnp.sum(jnp.where(zsel, xr, 0.0), axis=0, keepdims=True)
+    row_i = jnp.sum(jnp.where(zsel, xi, 0.0), axis=0, keepdims=True)
     jj = jax.lax.broadcasted_iota(jnp.int32, (dz, dz), 0)
     kk = jax.lax.broadcasted_iota(jnp.int32, (dz, dz), 1)
     jk = jj + kk
@@ -370,8 +416,7 @@ def _complete_zero_stick(R, dz, xr, xi, is_z, z_row):
     nz = (row_r != 0.0) | (row_i != 0.0)
     new_r = jnp.where(nz, row_r, mir_r)
     new_i = jnp.where(nz, row_i, -mir_i)
-    rowsel = (jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-              == z_row) & is_z
+    rowsel = zsel & is_z
     return jnp.where(rowsel, new_r, xr), jnp.where(rowsel, new_i, xi)
 
 
@@ -592,49 +637,64 @@ def run_decompress_zdft(re, im, dev_tables: tuple, mats: tuple,
 
 # -- forward kernel: z-DFT -> windowed compress gather -----------------------
 
-def _zdft_cmp_body(K, S_w, q, g, off_ref, first_ref, packed_ref,
-                   cr_ref, ci_ref, cs_ref, sc, slot, store):
-    """Shared per-step body of the forward fused kernel: transform the
-    DMA'd raw sticks, slice the chunk's flat window out, gather."""
-    xr = sc[slot, 0]
-    xi = sc[slot, 1]
-    yr, yi = _kara(xr, xi, cr_ref[...], ci_ref[...], cs_ref[...])
-    # (S_w, q*128) -> (S_w*q, 128): lane-preserving leading-dim split
-    fr = yr.reshape(S_w * q, TILE_LANE)
-    fi = yi.reshape(S_w * q, TILE_LANE)
-    win_re = jax.lax.dynamic_slice_in_dim(fr, off_ref[g], K, 0)
-    win_im = jax.lax.dynamic_slice_in_dim(fi, off_ref[g], K, 0)
+def _zdft_cmp_body(K, g, off_ref, first_ref, wnew_ref, packed_ref,
+                   cr_ref, ci_ref, cs_ref, sc, flat, wait, prefetch,
+                   store):
+    """Shared per-step body of the forward fused kernel. On a stick
+    window's first chunk: wait for its DMA, z-transform it into the
+    ``flat`` (rows, 128) scratch, then start the next window's DMA into
+    the now-free raw buffer. Every chunk slices its K-row source window
+    out of ``flat`` and gathers. The window is read back from a ref:
+    Mosaic lowers a dynamic row offset on a 128-lane ref load but not
+    ``dynamic_slice`` on a value."""
+    @pl.when(wnew_ref[g] == 1)
+    def _():
+        wait()
+        yr, yi = _kara(sc[0], sc[1], cr_ref[...], ci_ref[...], cs_ref[...])
+        # (S_w, q*128) -> (S_w*q, 128): lane-preserving leading-dim split
+        flat[0] = yr.reshape(flat.shape[1], TILE_LANE)
+        flat[1] = yi.reshape(flat.shape[1], TILE_LANE)
+
+    prefetch()
+    win_re = flat[0, pl.ds(off_ref[g], K), :]
+    win_im = flat[1, pl.ds(off_ref[g], K), :]
     acc_re, acc_im = _tile_compute_win(K, packed_ref[0], win_re, win_im)
     store(first_ref[g], acc_re, acc_im)
 
 
-def _kernel_zdft_cmp(K, S_w, q, s0_ref, off_ref, out_tile_ref, first_ref,
-                     packed_ref, cr_ref, ci_ref, cs_ref, re_hbm, im_hbm,
-                     out_re_ref, out_im_ref, sc, sem):
+def _win_copy(s0_ref, gg, S_w, hbm, sc, sem, chan, batch=None):
+    src = hbm if batch is None else hbm.at[batch]
+    return pltpu.make_async_copy(
+        src.at[pl.ds(pl.multiple_of(s0_ref[gg], TILE_SUB), S_w), :],
+        sc.at[chan], sem.at[chan])
+
+
+def _kernel_zdft_cmp(K, S_w, s0_ref, off_ref, out_tile_ref, first_ref,
+                     wnew_ref, packed_ref, cr_ref, ci_ref, cs_ref, re_hbm,
+                     im_hbm, out_re_ref, out_im_ref, sc, flat, sem):
     g = pl.program_id(0)
     n_g = pl.num_programs(0)
 
-    def dma(gg, slot, chan, hbm):
-        return pltpu.make_async_copy(
-            hbm.at[pl.ds(s0_ref[gg], S_w), :], sc.at[slot, chan],
-            sem.at[slot, chan])
-
-    def start(gg):
-        slot = jax.lax.rem(jnp.asarray(gg, jnp.int32), jnp.int32(2))
-        dma(gg, slot, 0, re_hbm).start()
-        dma(gg, slot, 1, im_hbm).start()
+    def copies(gg):
+        return (_win_copy(s0_ref, gg, S_w, re_hbm, sc, sem, 0),
+                _win_copy(s0_ref, gg, S_w, im_hbm, sc, sem, 1))
 
     @pl.when(g == 0)
     def _():
-        start(0)
+        for c in copies(0):
+            c.start()
 
-    @pl.when(g + 1 < n_g)
-    def _():
-        start(g + 1)
+    def wait():
+        for c in copies(g):
+            c.wait()
 
-    slot = jax.lax.rem(jnp.asarray(g, jnp.int32), jnp.int32(2))
-    dma(g, slot, 0, re_hbm).wait()
-    dma(g, slot, 1, im_hbm).wait()
+    def prefetch():
+        nxt = jnp.minimum(g + 1, n_g - 1)
+
+        @pl.when((g + 1 < n_g) & (wnew_ref[nxt] == 1))
+        def _():
+            for c in copies(nxt):
+                c.start()
 
     def store(frst, acc_re, acc_im):
         @pl.when(frst == 1)
@@ -647,42 +707,43 @@ def _kernel_zdft_cmp(K, S_w, q, s0_ref, off_ref, out_tile_ref, first_ref,
             out_re_ref[0] = out_re_ref[0] + acc_re
             out_im_ref[0] = out_im_ref[0] + acc_im
 
-    _zdft_cmp_body(K, S_w, q, g, off_ref, first_ref, packed_ref,
-                   cr_ref, ci_ref, cs_ref, sc, slot, store)
+    _zdft_cmp_body(K, g, off_ref, first_ref, wnew_ref, packed_ref,
+                   cr_ref, ci_ref, cs_ref, sc, flat, wait, prefetch, store)
 
 
-def _kernel_zdft_cmp_batched(K, S_w, q, s0_ref, off_ref, out_tile_ref,
-                             first_ref, packed_ref, cr_ref, ci_ref, cs_ref,
-                             re_hbm, im_hbm, out_re_ref, out_im_ref,
-                             sc, sem):
+def _kernel_zdft_cmp_batched(K, S_w, s0_ref, off_ref, out_tile_ref,
+                             first_ref, wnew_ref, packed_ref, cr_ref,
+                             ci_ref, cs_ref, re_hbm, im_hbm, out_re_ref,
+                             out_im_ref, sc, flat, sem):
+    """Batched grid (B, C): every batch walks the same windows (chunk 0
+    always opens one), and the prefetch crosses the batch boundary."""
     b = pl.program_id(0)
     g = pl.program_id(1)
     n_b = pl.num_programs(0)
     n_g = pl.num_programs(1)
-    step = b * n_g + g
 
-    def dma(bb, gg, slot, chan, hbm):
-        return pltpu.make_async_copy(
-            hbm.at[bb, pl.ds(s0_ref[gg], S_w), :], sc.at[slot, chan],
-            sem.at[slot, chan])
+    def copies(bb, gg):
+        return (_win_copy(s0_ref, gg, S_w, re_hbm, sc, sem, 0, bb),
+                _win_copy(s0_ref, gg, S_w, im_hbm, sc, sem, 1, bb))
 
-    def start(bb, gg, slot):
-        dma(bb, gg, slot, 0, re_hbm).start()
-        dma(bb, gg, slot, 1, im_hbm).start()
-
-    @pl.when(step == 0)
+    @pl.when((b == 0) & (g == 0))
     def _():
-        start(0, 0, 0)
+        for c in copies(0, 0):
+            c.start()
 
-    @pl.when(step + 1 < n_b * n_g)
-    def _():
-        nxt_b = jnp.where(g + 1 < n_g, b, b + 1)
-        nxt_g = jnp.where(g + 1 < n_g, g + 1, 0)
-        start(nxt_b, nxt_g, jax.lax.rem(step + 1, jnp.int32(2)))
+    def wait():
+        for c in copies(b, g):
+            c.wait()
 
-    slot = jax.lax.rem(step, jnp.int32(2))
-    dma(b, g, slot, 0, re_hbm).wait()
-    dma(b, g, slot, 1, im_hbm).wait()
+    def prefetch():
+        last = g + 1 == n_g
+        nxt_b = jnp.where(last, b + 1, b)
+        nxt_g = jnp.where(last, 0, g + 1)
+
+        @pl.when((nxt_b < n_b) & (wnew_ref[nxt_g] == 1))
+        def _():
+            for c in copies(nxt_b, nxt_g):
+                c.start()
 
     def store(frst, acc_re, acc_im):
         @pl.when(frst == 1)
@@ -695,8 +756,8 @@ def _kernel_zdft_cmp_batched(K, S_w, q, s0_ref, off_ref, out_tile_ref,
             out_re_ref[0, 0] = out_re_ref[0, 0] + acc_re
             out_im_ref[0, 0] = out_im_ref[0, 0] + acc_im
 
-    _zdft_cmp_body(K, S_w, q, g, off_ref, first_ref, packed_ref,
-                   cr_ref, ci_ref, cs_ref, sc, slot, store)
+    _zdft_cmp_body(K, g, off_ref, first_ref, wnew_ref, packed_ref,
+                   cr_ref, ci_ref, cs_ref, sc, flat, wait, prefetch, store)
 
 
 def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
@@ -721,28 +782,29 @@ def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
     K, S_w, dz = t.span_rows, t.win_sticks, t.dim_z
     q = dz // TILE_LANE
     scratch = [
-        pltpu.VMEM((2, 2, S_w, dz), jnp.float32),
-        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((2, S_w, dz), jnp.float32),
+        pltpu.VMEM((2, S_w * q, TILE_LANE), jnp.float32),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
     mat_specs = [pl.BlockSpec((dz, dz), lambda *a: (0, 0))] * 3
     if sr.ndim == 3:
         B = sr.shape[0]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,  # s0, off, out_tile, first
+            num_scalar_prefetch=5,  # s0, off, out_tile, first, wnew
             grid=(B, C),
             in_specs=[
                 pl.BlockSpec((1, TILE_SUB, TILE_LANE),
-                             lambda b, g, s0, of, ot, fs: (g, 0, 0)),
+                             lambda b, g, *_: (g, 0, 0)),
             ] + mat_specs + [
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=(
                 pl.BlockSpec((1, 1, TILE_SUB, TILE_LANE),
-                             lambda b, g, s0, of, ot, fs:
+                             lambda b, g, s0, of, ot, *_:
                              (b, ot[g], 0, 0)),
                 pl.BlockSpec((1, 1, TILE_SUB, TILE_LANE),
-                             lambda b, g, s0, of, ot, fs:
+                             lambda b, g, s0, of, ot, *_:
                              (b, ot[g], 0, 0)),
             ),
             scratch_shapes=scratch,
@@ -752,23 +814,23 @@ def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
                                  jnp.float32),
             jax.ShapeDtypeStruct((B, t.num_tiles, TILE_SUB, TILE_LANE),
                                  jnp.float32))
-        kern = functools.partial(_kernel_zdft_cmp_batched, K, S_w, q)
+        kern = functools.partial(_kernel_zdft_cmp_batched, K, S_w)
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(C,),
             in_specs=[
                 pl.BlockSpec((1, TILE_SUB, TILE_LANE),
-                             lambda g, s0, of, ot, fs: (g, 0, 0)),
+                             lambda g, *_: (g, 0, 0)),
             ] + mat_specs + [
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=(
                 pl.BlockSpec((1, TILE_SUB, TILE_LANE),
-                             lambda g, s0, of, ot, fs: (ot[g], 0, 0)),
+                             lambda g, s0, of, ot, *_: (ot[g], 0, 0)),
                 pl.BlockSpec((1, TILE_SUB, TILE_LANE),
-                             lambda g, s0, of, ot, fs: (ot[g], 0, 0)),
+                             lambda g, s0, of, ot, *_: (ot[g], 0, 0)),
             ),
             scratch_shapes=scratch,
         )
@@ -777,13 +839,13 @@ def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
                                  jnp.float32),
             jax.ShapeDtypeStruct((t.num_tiles, TILE_SUB, TILE_LANE),
                                  jnp.float32))
-        kern = functools.partial(_kernel_zdft_cmp, K, S_w, q)
-    s0, off, out_tile, first, packed = dev_tables
+        kern = functools.partial(_kernel_zdft_cmp, K, S_w)
+    s0, off, out_tile, first, wnew, packed = dev_tables
     cr, ci, cs = mats
     return pl.pallas_call(
         kern, out_shape=out_shape, grid_spec=grid_spec,
         interpret=interpret,
-    )(s0, off, out_tile, first, packed, cr, ci, cs, sr, si)
+    )(s0, off, out_tile, first, wnew, packed, cr, ci, cs, sr, si)
 
 
 def pad_sticks_planar(sr, si, src_sticks: int):

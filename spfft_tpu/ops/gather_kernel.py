@@ -228,14 +228,14 @@ WIDE_P = 8
 #: spans ~ 8/fill_fraction.
 WIDE_KP_CANDIDATES = (8, 12, 16, 24, 32)
 
-#: Max chunks per wide launch. NOT an SMEM budget: the TPU compile helper
-#: deterministically crashes (subprocess exit 1) compiling this kernel with
-#: a grid of ~2000+ steps — C=1961 compiles, C=2000 does not, across every
-#: (P, kp, K) probed (scripts/probe_wide_sweep.py bisect, 2026-07-30) —
-#: while the narrow kernel compiles at C=17k+. 1536 leaves margin in case
-#: the threshold shifts with kernel-body size; larger tables run as
-#: multiple tile-aligned launches (one extra launch per ~12.6M output
-#: slots — negligible next to the per-step win).
+#: Max chunks per wide launch: an SMEM budget. The scalar-prefetched
+#: tables live in the 1 MiB SMEM, where the (C, P//4) ``sub`` table takes
+#: 512 bytes a row, so C=2000 exceeds it ("Ran out of memory in memory
+#: space smem") while C=1536 compiles (tests/test_tpu_compile.py::
+#: test_wide_launch_at_chunk_limit); the narrow kernel's 1-D tables
+#: compile at C=17k+. Larger tables run as multiple tile-aligned launches
+#: (one extra launch per ~12.6M output slots — negligible next to the
+#: per-step win).
 WIDE_SEG_CHUNK_LIMIT = 1536
 
 #: Wide fallback ceiling, in chunks per super-tile: per-slot unit cost of
@@ -1039,10 +1039,10 @@ def wide_gather(re, im, row0, sub, out_tile, first, packed, *,
         return (jnp.concatenate(outs_re, axis=axis),
                 jnp.concatenate(outs_im, axis=axis))
     if re.ndim == 3 and re.shape[0] * row0.shape[0] > WIDE_SEG_CHUNK_LIMIT:
-        # The compile-crash threshold (see WIDE_SEG_CHUNK_LIMIT) is on the
-        # TOTAL grid step count; a batched launch compiles B * C steps, so
-        # big batches run as per-slab launches instead (loses cross-batch
-        # DMA prefetch only).
+        # Big batches run as per-slab launches (loses cross-batch DMA
+        # prefetch only). The split dates from a compiler that failed on
+        # B * C grid steps; the installed one's limit is the C-row SMEM
+        # tables (WIDE_SEG_CHUNK_LIMIT), which a batch does not grow.
         outs = [_wide_gather_call(re[b], im[b], row0, sub, out_tile, first,
                                   packed, span_rows=span_rows,
                                   kp_rows=kp_rows, p_tiles=p_tiles,
@@ -1083,9 +1083,7 @@ def _wide_gather_call(re, im, row0, sub, out_tile, first, packed, *,
     if re.ndim == 3:
         B = re.shape[0]
         if B * C > WIDE_SEG_CHUNK_LIMIT:
-            # The compile-crash threshold (WIDE_SEG_CHUNK_LIMIT) is on
-            # the TOTAL grid step count; big batches run per slab
-            # (loses cross-batch DMA prefetch only).
+            # big batches run per slab (see wide_gather)
             outs = [_wide_gather_call(
                 re[b], im[b], row0, sub, out_tile, first, packed,
                 span_rows=K, kp_rows=kp, p_tiles=P, num_super=num_super,

@@ -61,7 +61,7 @@ from .exchange import (all_to_all_blocks, build_compact_schedule,
                        ragged_exchange, pack_freq_to_blocks,
                        pack_space_to_blocks, ring_exchange_blocks,
                        unpack_blocks_to_grid, unpack_blocks_to_sticks)
-from .mesh import make_mesh, shard_map
+from .mesh import make_mesh
 from .overlap import build_overlap_schedule
 
 #: Environment default for the plan's ``overlap_chunks`` knob: split the
@@ -437,7 +437,7 @@ class DistributedTransformPlan:
                            and self._fused_dist is None
                            and self._fused_dist_fwd is None)
         shmap = functools.partial(
-            shard_map, mesh=self.mesh, in_specs=self._base_in_specs,
+            jax.shard_map, mesh=self.mesh, in_specs=self._base_in_specs,
             out_specs=P(self.axis_name), check_vma=self._check_vma)
         self._pair_jits = {}
         self._batched = None
@@ -1087,7 +1087,8 @@ class DistributedTransformPlan:
         # height are common); src_sticks and chunk counts differ, so pad
         # to the maxima with no-op chunks that store zeros into the
         # DUMMY output tile ``num_tiles`` (all-invalid selector words
-        # gather zeros; first=1 so nothing accumulates onto garbage).
+        # gather zeros; first=1 so nothing accumulates onto garbage;
+        # wnew=0 so they reuse the last window and DMA nothing).
         num_tiles = fused[0].num_tiles
         c_max = max(f.s0.shape[0] for f in fused)
         src_sticks = max(f.src_sticks for f in fused)
@@ -1099,14 +1100,16 @@ class DistributedTransformPlan:
                     np.concatenate([f.out_tile,
                                     np.full(p_, num_tiles, np.int32)]),
                     np.concatenate([f.first, np.ones(p_, np.int32)]),
+                    np.concatenate([f.wnew, np.zeros(p_, np.int32)]),
                     np.concatenate([f.packed,
                                     np.zeros((p_, 8, 128), np.int32)]))
 
         padded = [pad(f) for f in fused]
-        stacked = [np.stack([p_[i] for p_ in padded]) for i in range(5)]
+        stacked = [np.stack([p_[i] for p_ in padded]) for i in range(6)]
         rep = dataclasses.replace(
             fused[0], s0=padded[0][0], off=padded[0][1],
-            out_tile=padded[0][2], first=padded[0][3], packed=padded[0][4],
+            out_tile=padded[0][2], first=padded[0][3], wnew=padded[0][4],
+            packed=padded[0][5],
             num_tiles=num_tiles + 1, src_sticks=src_sticks,
             span_rows=k_u, num_out=mv)
         # UNSCALED forward matrices: Scaling.FULL stays the same
@@ -1648,7 +1651,7 @@ class DistributedTransformPlan:
         """shard_map wrapper for the fused-pair entry points: base specs
         plus one sharded spec per fn_arg."""
         return functools.partial(
-            shard_map, mesh=self.mesh,
+            jax.shard_map, mesh=self.mesh,
             in_specs=self._base_in_specs
             + (P(self.axis_name),) * n_fn_args,
             out_specs=P(self.axis_name), check_vma=self._check_vma)
@@ -2018,7 +2021,7 @@ class DistributedTransformPlan:
         overlap, multi_transform_internal.hpp:47-94)."""
         if self._batched is None:
             shmap = functools.partial(
-                shard_map, mesh=self.mesh, in_specs=self._base_in_specs,
+                jax.shard_map, mesh=self.mesh, in_specs=self._base_in_specs,
                 out_specs=P(self.axis_name), check_vma=self._check_vma)
             self._batched = {
                 "backward": jax.jit(shmap(self._backward_body_batched)),

@@ -20,20 +20,6 @@ from ..errors import InvalidParameterError
 SHARD_AXIS = "shards"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: newer JAX exposes
-    ``jax.shard_map`` (replication checking spelled ``check_vma``);
-    0.4.x ships it as ``jax.experimental.shard_map.shard_map`` with the
-    same check spelled ``check_rep``. One wrapper so every SPMD entry
-    point in this library works on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def make_mesh(num_shards: Optional[int] = None,
               devices: Optional[Sequence[jax.Device]] = None,
               axis_name: str = SHARD_AXIS) -> Mesh:

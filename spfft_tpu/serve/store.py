@@ -425,11 +425,8 @@ def export_aot_blobs(plan: TransformPlan) -> Dict[str, bytes]:
     traced function)."""
     if getattr(plan, "_ds", False):
         return {}
-    try:
-        import jax
-        from jax import export as jax_export
-    except ImportError:
-        return {}
+    import jax
+    from jax import export as jax_export
     plan._finalize()
     tab_avals = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -487,12 +484,8 @@ def _install_aot(plan: TransformPlan, header: dict, arrays: dict) -> int:
     # fault seam: an injected failure here flows into load_key's
     # poisoned-restore handling -> typed CORRUPT reject + clean rebuild
     _faults.check_site("store.aot")
-    try:
-        import jax
-        from jax import export as jax_export
-    except ImportError:
-        _obs.record_store_aot_skip("jax_export_unavailable")
-        return 0
+    import jax
+    from jax import export as jax_export
     backend = jax.default_backend()
     installed = {}
     for key in aot_meta:

@@ -64,7 +64,7 @@ def test_improvement_always_passes(tmp_path):
 def test_wrapped_bench_r_format_and_latest_selection(tmp_path, capsys):
     # driver BENCH_r*.json format resolves through "parsed", and the
     # highest-numbered reference wins
-    _write(tmp_path / "BENCH_r02.json", 0.020, wrap=True)
+    _write(tmp_path / "BENCH_r07.json", 0.020, wrap=True)
     _write(tmp_path / "BENCH_r10.json", 0.010, wrap=True)
     fresh = _write(tmp_path / "fresh.json", 0.0105)
     rc = bench_regress.main(["--fresh", fresh, "--root", str(tmp_path)])

@@ -77,7 +77,7 @@ def test_cli_serve_reports_metrics(tmp_path):
 def test_cli_store_dir_cold_warm_ab(tmp_path):
     """--store-dir (round 13): the JSON must carry the cold/warm pair
     bench_regress.py compares — a true cold start (empty store, one
-    build + spill) and a fresh-subprocess warm boot with zero builds."""
+    build + spill) and a fresh-registry warm boot with zero builds."""
     out = tmp_path / "bench.json"
     store_dir = tmp_path / "store"
     assert main(["-d", "12", "-r", "1", "-s", "0.5",

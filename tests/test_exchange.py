@@ -17,7 +17,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from spfft_tpu import TransformType
 from spfft_tpu.parallel import make_distributed_plan, make_mesh
-from spfft_tpu.parallel.mesh import shard_map
 from spfft_tpu.parallel.exchange import (all_to_all_blocks,
                                          pack_freq_to_blocks,
                                          pack_space_to_blocks,
@@ -63,7 +62,7 @@ def test_exchange_round_trip_restores_sticks(mechanism):
         blocks2 = mechanism(blocks2, plan.axis_name, None)
         return unpack_blocks_to_sticks(blocks2, z_src)[None]
 
-    shmap = shard_map(
+    shmap = jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.axis_name), P(), P(), P(), P()),
         out_specs=P(plan.axis_name))
@@ -96,7 +95,7 @@ def test_exchange_grid_placement():
         return unpack_blocks_to_grid(blocks, col_inv, dp.dim_y,
                                      dp.dim_x_freq)[None]
 
-    shmap = shard_map(
+    shmap = jax.shard_map(
         body, mesh=plan.mesh, in_specs=(P(plan.axis_name), P(), P()),
         out_specs=P(plan.axis_name))
     grids = np.asarray(jax.jit(shmap)(

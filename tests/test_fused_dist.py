@@ -37,7 +37,7 @@ def fused_env(monkeypatch):
     """The CPU fused lane: mdft T pipeline forced on (the fused seam
     only exists there), the fused kernels in interpret mode, and the
     forward cost gate widened — the random fuzz workloads at these toy
-    dims trip the default RECOMPUTE_LIMIT (covered separately in
+    dims can trip the default RECOMPUTE_LIMIT (covered separately in
     test_dist_fused_fwd_recompute_gate)."""
     monkeypatch.setenv("SPFFT_TPU_FORCE_MATMUL_DFT", "1")
     monkeypatch.setenv("SPFFT_TPU_FUSED_INTERPRET", "1")
@@ -256,12 +256,12 @@ def test_dist_fused_overlap_composes(fused_env):
 
 
 def test_dist_fused_fwd_recompute_gate(fused_env, monkeypatch):
-    """At the default RECOMPUTE_LIMIT this workload's window-overlap DFT
+    """At the tightest RECOMPUTE_LIMIT this workload's stick-window DFT
     recompute blows the forward cost model: the forward twin declines
     with a recorded reason while the backward stays active, and the
     SPFFT_TPU_FUSED_RECOMPUTE_LIMIT knob lifts it (the fused_env
     fixture's widened gate is what every other test here rides)."""
-    monkeypatch.delenv("SPFFT_TPU_FUSED_RECOMPUTE_LIMIT")
+    monkeypatch.setenv("SPFFT_TPU_FUSED_RECOMPUTE_LIMIT", "1")
     parts, planes = _parts_planes(TransformType.R2C)
     plan = _build(TransformType.R2C, parts, planes, fused=True)
     assert plan.fused_dist_bwd_active

@@ -426,17 +426,18 @@ def test_dist_gate_no_matmul_dft(monkeypatch):
     assert plan.fused_dist_fwd_fallback_reason == "no_matmul_dft"
 
 
-def test_dist_fwd_twin_recompute_counter_recorded(fused_env):
+def test_dist_fwd_twin_recompute_counter_recorded(fused_env, monkeypatch):
     """The forward twin's recompute_blowup decline records under the
     dist_fused_zdft_compress stage (declared in METRIC_SPECS) and the
     series surfaces through the Prometheus exposition — the runtime
     coverage for the new fallback stage label."""
     from spfft_tpu import obs
+    monkeypatch.setenv("SPFFT_TPU_FUSED_RECOMPUTE_LIMIT", "1")
     before = obs.GLOBAL_COUNTERS.get(
         "spfft_plan_pallas_fallback_total",
         stage="dist_fused_zdft_compress", reason="recompute_blowup")
-    # at the default RECOMPUTE_LIMIT this workload's window-overlap
-    # recompute blows the forward cost model (the backward stays active)
+    # at the tightest limit this workload's stick-window recompute
+    # blows the forward cost model (the backward stays active)
     plan = _dist_plan()
     assert plan.fused_dist_bwd_active
     assert plan.fused_dist_fwd_fallback_reason == "recompute_blowup"

@@ -1,0 +1,252 @@
+"""Compile-only checks of the main path's Pallas kernels for a TPU v5e.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached, so these tests catch what interpret mode cannot
+(a slice not aligned to the tiling, more VMEM or SMEM than a kernel may
+use) at no chip time. Nothing runs: a pass says the installed Mosaic
+compiler accepts each kernel at the 256^3 plan's real shapes, never that
+results or times are right. ``tpu_custom_call`` in the compiled text
+proves the kernel is in the program.
+
+Everything built from the topology is built in fixtures (never at
+import), so every pytest-xdist worker collects the same tests and only
+the worker that runs this file loads the TPU compiler. The plans are
+built with ``jax.default_backend`` reporting ``"tpu"``, so their
+trace-time gates take the branches the chip takes.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spfft_tpu import Scaling, TransformType, make_local_plan
+from spfft_tpu.ops import dft
+from spfft_tpu.ops import dft_kernel as dk
+from spfft_tpu.ops import fused_kernel as fkm
+from spfft_tpu.ops import gather_kernel as gk
+from spfft_tpu.utils.workloads import spherical_cutoff_triplets
+
+N = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for(one_chip):
+    """``compile_for(fn, *args)``: compile ``fn`` for the described chip
+    with every array argument turned into a shape on it; returns the
+    compiled text. The persistent cache is off around these compiles (an
+    entry written for a described chip cannot be read back here), and so
+    is the suite's x64 mode: the chip runs without it, and Mosaic
+    refuses the 64-bit block indices it would give the kernels."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    was_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype
+                                    if not hasattr(x, "dtype") else x.dtype,
+                                    sharding=one_chip)
+
+    def run(fn, *args):
+        args = jax.tree_util.tree_map(shape, args)
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield run
+    jax.config.update("jax_enable_compilation_cache", was)
+    jax.config.update("jax_enable_x64", was_x64)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def as_tpu():
+    """Report a TPU backend for the module's plan builds and traces."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        yield
+
+
+@pytest.fixture(scope="module")
+def plans(as_tpu, compile_for):
+    tr = spherical_cutoff_triplets(N)
+    out = {
+        "c2c": make_local_plan(TransformType.C2C, N, N, N, tr,
+                               precision="single"),
+        "r2c": make_local_plan(TransformType.R2C, N, N, N,
+                               tr[tr[:, 0] >= 0], precision="single"),
+    }
+    yield out
+    for p in out.values():
+        p.close()
+
+
+def _pipeline(plan, which):
+    """(fn, args) of one public executable of ``plan``, args as host
+    arrays of the shapes the chip would see."""
+    tabs = plan._tables_hot
+    v = np.zeros((plan.index_plan.num_values, 2), np.float32)
+    if which == "backward":
+        return plan._backward_jit, (v, tabs)
+    space = jax.eval_shape(plan._backward_jit, v, tabs)
+    if which == "forward":
+        return plan._forward_jit[Scaling.NONE], (space, tabs)
+    if which == "pair":
+        return functools.partial(plan._pair_impl, scaled=False,
+                                 fn=None), (v, tabs)
+    jits = plan._batched_jits()
+    vb = np.zeros((2,) + v.shape, np.float32)
+    if which == "batched_backward":
+        return jits["backward"], (vb, tabs)
+    sb = jax.eval_shape(jits["backward"], vb, tabs)
+    return jits[Scaling.NONE], (sb, tabs)
+
+
+@pytest.mark.parametrize("which", ["backward", "forward", "pair",
+                                   "batched_backward", "batched_forward"])
+@pytest.mark.parametrize("kind", ["c2c", "r2c"])
+def test_pipeline_256_compiles(plans, compile_for, kind, which):
+    """The whole executable, gather + fused kernels included, as the
+    chip runs it: both fused directions must be active, and the
+    installed compiler must accept the program."""
+    plan = plans[kind]
+    assert plan._pallas_active
+    assert plan._fused["dec"] is not None and plan._fused["cmp"] is not None
+    fn, args = _pipeline(plan, which)
+    assert "tpu_custom_call" in compile_for(fn, *args)
+
+
+def _gather_inputs(plan):
+    ip = plan.index_plan
+    num_slots = plan._s_pad * ip.dim_z
+    return num_slots, gk.compression_gather_inputs(
+        ip.value_indices.astype(np.int64), num_slots)
+
+
+@pytest.mark.parametrize("kind", ["narrow", "wide"])
+@pytest.mark.parametrize("direction", ["dec", "cmp"])
+def test_gather_kernel_256_compiles(plans, compile_for, kind, direction):
+    """``run_gather`` on the 256^3 C2C plan's real compression tables;
+    wide tables run as several launches of at most
+    ``WIDE_SEG_CHUNK_LIMIT`` chunks, chained by output aliasing."""
+    plan = plans["c2c"]
+    num_slots, ((dec_idx, occ), (cmp_idx, cmp_valid)) = \
+        _gather_inputs(plan)
+    idx, valid, num_src = ((dec_idx, occ, plan.index_plan.num_values)
+                           if direction == "dec"
+                           else (cmp_idx, cmp_valid, num_slots))
+    build = (gk.build_wide_gather_tables if kind == "wide"
+             else gk.build_monotone_gather_tables)
+    t = build(idx, valid, num_src)
+    assert t is not None
+    src = np.zeros((t.src_rows, gk.TILE_LANE), np.float32)
+    dev = jax.tree_util.tree_map(np.asarray, gk.gather_device_tables(t))
+    text = compile_for(lambda re, im, d: gk.run_gather(re, im, d, t),
+                       src, src, dev)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [0, 2])
+@pytest.mark.parametrize("kind", ["c2c", "r2c"])
+def test_fused_decompress_256_compiles(plans, compile_for, kind, batch):
+    plan = plans[kind]
+    t = plan._fused["dec"]
+    assert (t.zinfo is not None) == (kind == "r2c")
+    lead = (batch,) if batch else ()
+    src = np.zeros(lead + (t.src_rows, gk.TILE_LANE), np.float32)
+    dev = tuple(np.asarray(a) for a in fkm.decompress_device_tables(t))
+    mats = tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(N, dft.BACKWARD))
+    text = compile_for(
+        lambda re, im, d, m: fkm.run_decompress_zdft(re, im, d, m, t),
+        src, src, dev, mats)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [0, 2])
+@pytest.mark.parametrize("kind", ["c2c", "r2c"])
+def test_fused_compress_256_compiles(plans, compile_for, kind, batch):
+    plan = plans[kind]
+    t = plan._fused["cmp"]
+    lead = (batch,) if batch else ()
+    sticks = np.zeros(lead + (t.src_sticks, N), np.float32)
+    dev = tuple(np.asarray(a) for a in fkm.compress_device_tables(t))
+    mats = tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(N, dft.FORWARD))
+    text = compile_for(
+        lambda sr, si, d, m: fkm.run_zdft_compress(sr, si, d, m, t),
+        sticks, sticks, dev, mats)
+    assert "tpu_custom_call" in text
+
+
+def _mats(n):
+    return tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(n, dft.BACKWARD))
+
+
+@pytest.mark.parametrize("n,rows", [(N, 51456), (384, 1536), (512, 1536)])
+def test_stage_kernel_compiles(compile_for, n, rows):
+    """``pdft_last``: the 256^3 z-stage shape, and the larger axis
+    classes where the 5.5 MB VMEM budget picks smaller row tiles (the
+    next tile up, tm=1024 at 384 and tm=512 at 512, runs out of VMEM
+    in this compiler)."""
+    x = np.zeros((rows, n), np.float32)
+    text = compile_for(lambda a, b, m: dk.pdft_last(a, b, m), x, x,
+                       _mats(n))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n,planes", [(N, N), (320, 4)])
+def test_xy_kernel_compiles(as_tpu, compile_for, n, planes):
+    """The two-stage xy kernels: ``pdft2`` on the 256^3 local grid and
+    ``cdft2_xy`` (the distributed xy dispatcher) at the largest eligible
+    axis class, 320."""
+    x = np.zeros((planes, n, n), np.float32)
+    m = _mats(n)
+    text = compile_for(lambda a, b, m1, m2: dk.pdft2(a, b, m1, m2),
+                       x, x, m, m)
+    assert "tpu_custom_call" in text
+
+    def xy(a, b, m1, m2):
+        y = dft.cdft2_xy(a + 1j * b, m1, m2)
+        return jnp.real(y), jnp.imag(y)
+
+    assert "tpu_custom_call" in compile_for(xy, x, x, m, m)
+
+
+def test_wide_launch_at_chunk_limit(compile_for):
+    """One wide launch of ``WIDE_SEG_CHUNK_LIMIT`` chunks compiles; the
+    cap exists because the scalar-prefetched tables live in the 1 MiB
+    SMEM, where the (C, P//4) ``sub`` table takes 512 bytes a row."""
+    C, P = gk.WIDE_SEG_CHUNK_LIMIT, gk.WIDE_P
+    src = np.zeros((70000, gk.TILE_LANE), np.float32)
+    i32 = functools.partial(np.zeros, dtype=np.int32)
+    tabs = (i32(C), i32((C, P // 4)), i32(C), i32(C),
+            np.zeros((C, P * 8, gk.TILE_LANE), np.int16))
+    text = compile_for(
+        lambda re, im, *t: gk._wide_gather_call(
+            re, im, *t, span_rows=64, kp_rows=16, p_tiles=P, num_super=C,
+            interpret=False),
+        src, src, *tabs)
+    assert "tpu_custom_call" in text

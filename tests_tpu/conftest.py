@@ -6,8 +6,6 @@ Mosaic codegen, T(8,128) layout behavior, pair-IO boundaries and the
 wide-kernel DMA path have a documented history of silent corruption
 (the round-2 rank-3 irfft bug, the round-4 wide-kernel compile crash)
 that CPU-pinned tests structurally cannot see — round-4 verdict item 3.
-
-Recorded green log: docs/ci_tpu_r05.log.
 """
 
 import jax
