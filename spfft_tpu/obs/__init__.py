@@ -67,6 +67,7 @@ __all__ = [
     "export_trace", "trace_events", "prometheus_text",
     "parse_prometheus_text",
     "MetricsServer", "METRICS_PORT_ENV", "port_from_env",
+    "compile_span",
     "record_compile", "record_plan_build", "record_exchange_plan",
     "record_hlo_counts", "record_plan_fallback", "record_store",
     "record_store_aot_skip",
@@ -136,6 +137,13 @@ def record_plan_fallback(stage: str, reason: str) -> None:
                               args={"stage": stage, "reason": reason})
 
 
+def _count_compile(kind: str, seconds: float) -> None:
+    GLOBAL_COUNTERS.inc("spfft_compile_events_total", 1,
+                        help="Compile-path events by kind.", kind=kind)
+    GLOBAL_COUNTERS.inc("spfft_compile_seconds_total", seconds,
+                        help="Compile-path seconds by kind.", kind=kind)
+
+
 def record_compile(what: str, seconds: float, t0: Optional[float] = None,
                    **info) -> None:
     """One compile-ish event (registry build, prewarm, pin prewarm,
@@ -143,10 +151,7 @@ def record_compile(what: str, seconds: float, t0: Optional[float] = None,
     when tracing is on. ``t0`` is the ``time.perf_counter()`` start
     when the caller measured a real interval; omitted, the span is
     recorded at now-minus-``seconds``."""
-    GLOBAL_COUNTERS.inc("spfft_compile_events_total", 1,
-                        help="Compile-path events by kind.", kind=what)
-    GLOBAL_COUNTERS.inc("spfft_compile_seconds_total", seconds,
-                        help="Compile-path seconds by kind.", kind=what)
+    _count_compile(what, seconds)
     if active():
         t1 = (t0 + seconds) if t0 is not None else time.perf_counter()
         args = {k: v for k, v in info.items()
@@ -156,18 +161,59 @@ def record_compile(what: str, seconds: float, t0: Optional[float] = None,
                                args=args or None)
 
 
+class compile_span:
+    """One stage of a plan build, as a context manager: a real
+    ``compile.<kind>`` span on the ``compile`` track while tracing is on
+    (mirrored into the profiler, see :meth:`Tracer.span`), and, always,
+    its seconds and one event under ``kind`` in
+    ``spfft_compile_{seconds,events}_total``, failed or not. ``parent``
+    (a span or None) gives the span its parent link and trace id; without
+    one it takes a new trace id. ``__enter__`` returns the span, or None
+    with tracing off."""
+
+    __slots__ = ("kind", "parent", "args", "_t0", "_scope")
+
+    def __init__(self, kind: str, parent: Optional[Span] = None,
+                 args: Optional[dict] = None):
+        self.kind = kind
+        self.parent = parent
+        self.args = args
+        self._scope = None
+
+    def __enter__(self) -> Optional[Span]:
+        self._t0 = time.perf_counter()
+        if not active():
+            return None
+        trace_id = self.parent.trace_id if self.parent is not None \
+            else GLOBAL_TRACER.new_trace_id()
+        self._scope = GLOBAL_TRACER.span(
+            f"compile.{self.kind}", cat="compile", track="compile",
+            trace_id=trace_id, parent=self.parent, args=self.args)
+        return self._scope.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._scope is not None:
+            self._scope.__exit__(exc_type, exc, tb)
+        _count_compile(self.kind, time.perf_counter() - self._t0)
+
+
 def record_plan_build(plan, seconds: float,
                       t0: Optional[float] = None) -> None:
     """Called by ``TransformPlan.__init__`` (kind=local) and the
     distributed plan (kind=distributed) with the measured construction
-    time."""
+    time: the foreground of the build, which for a local plan ends where
+    its background compression-table build starts (that build counts
+    under ``kind="compression_tables"`` in the compile counters, and the
+    time a caller waits for it under ``kind="plan_join"``). A local plan
+    records its ``compile.plan_build`` span itself, as it builds; here
+    only a distributed plan gets one, after the fact."""
     kind = ("distributed" if hasattr(plan, "dist_plan") else "local")
     GLOBAL_COUNTERS.inc("spfft_plan_builds_total", 1,
                         help="Transform plans constructed.", kind=kind)
     GLOBAL_COUNTERS.inc("spfft_plan_build_seconds_total", seconds,
                         help="Seconds spent constructing plans.",
                         kind=kind)
-    if active():
+    if kind == "distributed" and active():
         t1 = (t0 + seconds) if t0 is not None else time.perf_counter()
         try:
             args = {"kind": kind, "precision": plan.precision,

@@ -34,7 +34,6 @@ without bound.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import os
 import threading
@@ -158,8 +157,9 @@ class Tracer:
 
     Spans: :meth:`begin` / :meth:`finish` (cross-thread: begin on a
     submitter thread, finish on the dispatcher), :meth:`span` (context
-    manager, error status captured), :meth:`complete` (an interval
-    measured elsewhere, recorded after the fact — plan builds use it).
+    manager, error status captured, mirrored into the profiler),
+    :meth:`complete` (an interval measured elsewhere, recorded after the
+    fact — distributed plan builds use it).
     Point events: :meth:`instant` (annotations: retries, quarantines),
     :meth:`counter` (numeric series: per-chunk wire bytes).
     """
@@ -279,16 +279,15 @@ class Tracer:
             self._append_locked(span)
         return span
 
-    @contextlib.contextmanager
-    def span(self, name: str, **kwargs):
-        sp = self.begin(name, **kwargs)
-        try:
-            yield sp
-        except BaseException as exc:
-            self.finish(sp, status="error", error=type(exc).__name__)
-            raise
-        else:
-            self.finish(sp)
+    def span(self, name: str, **kwargs) -> "_SpanScope":
+        """Same-thread span as a context manager (``begin`` arguments),
+        closed with ``status="error"`` and the exception's type name when
+        its body raises. While tracing is active it also enters a
+        ``jax.profiler.TraceAnnotation`` of the same name, so that in a
+        profiler session the span lies on the host plane of the XSpace,
+        on the device ops' clock. ``begin``/``finish`` pairs (which may
+        cross threads) and ``complete`` stay in this tracer only."""
+        return _SpanScope(self, name, kwargs)
 
     # -- point events -------------------------------------------------------
     def instant(self, name: str, cat: str = "serve",
@@ -342,6 +341,45 @@ class Tracer:
                     "buffered": len(self._events),
                     "dropped": self._dropped,
                     "sample_rate": self._sample_rate}
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``. The
+    class is looked up on each call: JAX is imported only once a span is
+    mirrored, and tests can substitute the class."""
+    import jax.profiler
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
+class _SpanScope:
+    """The context manager :meth:`Tracer.span` returns."""
+
+    __slots__ = ("_tracer", "_name", "_kwargs", "_span", "_annotation")
+
+    def __init__(self, tracer: Tracer, name: str, kwargs: dict):
+        self._tracer = tracer
+        self._name = name
+        self._kwargs = kwargs
+        self._span = None
+        self._annotation = None
+
+    def __enter__(self) -> Span:
+        # span: closed-by(_SpanScope.__exit__)
+        self._span = self._tracer.begin(self._name, **self._kwargs)
+        if _enabled:
+            self._annotation = _annotation(self._name)
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._tracer.finish(self._span)
+        else:
+            self._tracer.finish(self._span, status="error",
+                                error=exc_type.__name__)
 
 
 class RequestTrace:

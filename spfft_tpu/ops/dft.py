@@ -369,6 +369,7 @@ def _swap_pair(gr, gi):
     return jnp.swapaxes(gr, -1, -2), jnp.swapaxes(gi, -1, -2)
 
 
+@jax.named_scope("spfft.xy")
 def pdft2_minor(xr, xi, mats1, mats2):
     """[minor DFT (mats1), transpose, minor DFT (mats2)] on planar
     complex ``(P, A, B)`` operands -> ``(P, B', A')``: one fused kernel
@@ -381,6 +382,7 @@ def pdft2_minor(xr, xi, mats1, mats2):
     return pdft_last_opt(gr, gi, mats2)
 
 
+@jax.named_scope("spfft.xy")
 def prdft2_minor(x, mats1, mats2):
     """R2C head twin of :func:`pdft2_minor`: real in, rdft stage 1."""
     if _fits2_ok("rc", x, mats1, mats2):
@@ -407,6 +409,7 @@ def cdft2_xy(x, mats_minor, mats_mid):
     return jnp.swapaxes(y, -1, -2)
 
 
+@jax.named_scope("spfft.xy")
 def pdft2_minor_cr(xr, xi, mats1, mats2):
     """C2R tail twin of :func:`pdft2_minor`: irdft stage 2, real out."""
     if _fits2_ok("cr", xr, mats1, mats2):

@@ -546,6 +546,7 @@ def _kernel_dec_zdft_batched(K, P, R, dz, complete, *refs):
                    cr_ref, ci_ref, cs_ref, write, acc, sc, slot)
 
 
+@jax.named_scope("spfft.decompress_z")
 def run_decompress_zdft(re, im, dev_tables: tuple, mats: tuple,
                         t: FusedDecompressTables,
                         interpret: bool = False):
@@ -760,6 +761,7 @@ def _kernel_zdft_cmp_batched(K, S_w, s0_ref, off_ref, out_tile_ref,
                    cr_ref, ci_ref, cs_ref, sc, flat, wait, prefetch, store)
 
 
+@jax.named_scope("spfft.z_compress")
 def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
                       t: FusedCompressTables,
                       interpret: bool = False):
@@ -848,6 +850,7 @@ def run_zdft_compress(sr, si, dev_tables: tuple, mats: tuple,
     )(s0, off, out_tile, first, wnew, packed, cr, ci, cs, sr, si)
 
 
+@jax.named_scope("spfft.layout")
 def pad_sticks_planar(sr, si, src_sticks: int):
     """Zero-pad planar (num_sticks, dim_z) stick channels — or batched
     (B, num_sticks, dim_z) — to the ``src_sticks`` rows the forward

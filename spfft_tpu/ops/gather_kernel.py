@@ -1172,6 +1172,7 @@ def gather_device_tables(t) -> tuple:
     return tuple(out)
 
 
+@jax.named_scope("spfft.gather")
 def run_gather(re, im, dev_tables: tuple, t, interpret: bool = False):
     """Run whichever kernel matches ``t`` (WideGatherTables or
     MonotoneGatherTables) on planar sources; returns (out_re, out_im)
@@ -1252,6 +1253,7 @@ def run_monotone_gather(values_il, tables: MonotoneGatherTables,
     return run_gather_values(values_il, tables, device_tables, interpret)
 
 
+@jax.named_scope("spfft.layout")
 def planar_from_interleaved(values_il, src_rows: int, pair: bool = False):
     """Value array -> two zero-padded (src_rows, 128) planar arrays.
 
@@ -1279,6 +1281,7 @@ def planar_from_interleaved(values_il, src_rows: int, pair: bool = False):
     return re, im
 
 
+@jax.named_scope("spfft.layout")
 def planar_from_complex(x, src_rows: int):
     """Complex (S, Z) sticks — or batched (B, S, Z) — -> two zero-padded
     (src_rows, 128) planar arrays (leading B preserved). Goes straight
@@ -1295,6 +1298,7 @@ def planar_from_complex(x, src_rows: int):
             jnp.pad(im_flat, pad).reshape(shape))
 
 
+@jax.named_scope("spfft.layout")
 def interleaved_from_planar(out_re, out_im, num_out: int,
                             pair: bool = False):
     """Kernel outputs -> (num_out, 2) interleaved ((B, num_out, 2) when

@@ -51,6 +51,7 @@ from ..errors import InvalidParameterError, ParameterMismatchError
 from ..indexing import (build_index_plan, check_stick_duplicates,
                         occupied_x_window, window_sub_cols)
 from ..ops import stages
+from ..plan import _entry
 from ..timing import timed_transform
 from ..types import ExchangeType, Scaling, TransformType
 from ..utils.dtypes import (as_interleaved, complex_dtype,
@@ -443,8 +444,9 @@ class DistributedTransformPlan:
         self._batched = None
         self._backward_jit = jax.jit(shmap(self._backward_body))
         self._forward_jit = {
-            s: jax.jit(shmap(functools.partial(self._forward_body,
-                                               scaled=(s == Scaling.FULL))))
+            s: jax.jit(shmap(_entry(f"_forward_body_{s.name.lower()}",
+                                    self._forward_body,
+                                    scaled=(s == Scaling.FULL))))
             for s in (Scaling.NONE, Scaling.FULL)
         }
         # exchange observability (spfft_tpu.obs): plan-build span plus
@@ -1702,8 +1704,9 @@ class DistributedTransformPlan:
         jitted = self._pair_jits.get(key)
         if jitted is None:
             jitted = jax.jit(self._pair_shmap(len(fn_args))(
-                functools.partial(self._pair_body,
-                                  scaled=(scaling is Scaling.FULL), fn=fn)))
+                _entry(f"_pair_body_{scaling.name.lower()}",
+                       self._pair_body, scaled=(scaling is Scaling.FULL),
+                       fn=fn)))
             self._pair_jits[key] = jitted
         with timed_transform("apply_pointwise") as box:
             box.value = jitted(values, *self._device_tables, *fn_args)
@@ -2025,9 +2028,11 @@ class DistributedTransformPlan:
                 out_specs=P(self.axis_name), check_vma=self._check_vma)
             self._batched = {
                 "backward": jax.jit(shmap(self._backward_body_batched)),
-                Scaling.NONE: jax.jit(shmap(functools.partial(
+                Scaling.NONE: jax.jit(shmap(_entry(
+                    "_forward_body_batched_none",
                     self._forward_body_batched, scaled=False))),
-                Scaling.FULL: jax.jit(shmap(functools.partial(
+                Scaling.FULL: jax.jit(shmap(_entry(
+                    "_forward_body_batched_full",
                     self._forward_body_batched, scaled=True))),
             }
         return self._batched

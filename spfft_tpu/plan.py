@@ -21,7 +21,7 @@ complex format.
 
 from __future__ import annotations
 
-import functools
+import contextlib
 import logging
 import time as _time
 from typing import Optional
@@ -53,6 +53,17 @@ from .utils.dtypes import (as_interleaved, complex_dtype,
                            real_dtype)
 
 import dataclasses as _dataclasses
+
+
+def _entry(name: str, impl, /, **kwargs):
+    """``impl`` with ``kwargs`` bound, as a function named ``name``. JAX
+    names a program after the function it jits (``jit_<name>``), and a
+    ``functools.partial`` has no name: its program would be
+    ``jit__unknown``, which no trace can tell from another."""
+    def entry(*args):
+        return impl(*args, **kwargs)
+    entry.__name__ = entry.__qualname__ = name
+    return entry
 
 
 @_dataclasses.dataclass(frozen=True)
@@ -142,10 +153,33 @@ class TransformPlan:
                  donate_inputs: bool = False,
                  max_rel_error: Optional[float] = None,
                  device_double: Optional[bool] = None,
-                 _restore: Optional[PlanTables] = None):
+                 _restore: Optional[PlanTables] = None,
+                 _trace_id: Optional[int] = None):
+        from . import obs as _obs
         from .utils.platform import enable_persistent_compilation_cache
         enable_persistent_compilation_cache()
-        _t0_build = _time.perf_counter()
+        t0 = _time.perf_counter()
+        scope = contextlib.nullcontext()
+        if _obs.active():
+            scope = _obs.GLOBAL_TRACER.span(
+                "compile.plan_build", cat="compile", track="compile",
+                trace_id=(_obs.GLOBAL_TRACER.new_trace_id()
+                          if _trace_id is None else _trace_id),
+                args={"kind": "local", "precision": precision,
+                      "dims": f"{index_plan.dim_x}x{index_plan.dim_y}"
+                              f"x{index_plan.dim_z}"})
+        #: the build's ``compile.plan_build`` span (None with tracing
+        #: off), parent of the background table build's spans
+        with scope as self._build_span:
+            self._construct(index_plan, precision, use_pallas,
+                            donate_inputs, max_rel_error, device_double,
+                            _restore)
+        _obs.record_plan_build(self, _time.perf_counter() - t0, t0)
+
+    def _construct(self, index_plan, precision, use_pallas, donate_inputs,
+                   max_rel_error, device_double, _restore) -> None:
+        """The foreground of the plan build; the compression-table build
+        it starts runs on past its end."""
         #: When True, the fused round-trip executables (apply_pointwise /
         #: iterate_pointwise) DONATE their values argument: the output has
         #: the same shape, so XLA aliases the input buffer into it, cutting
@@ -340,12 +374,7 @@ class TransformPlan:
         # (the serving dispatcher, or the single caller thread).
         self._fused_demotions = {}
         self._backward_jit = jax.jit(self._backward_impl)
-        self._forward_jit = {
-            Scaling.NONE: jax.jit(functools.partial(self._forward_impl,
-                                                    scaled=False)),
-            Scaling.FULL: jax.jit(functools.partial(self._forward_impl,
-                                                    scaled=True)),
-        }
+        self._forward_jit = self._scaled_jits(self._forward_impl)
         # the FOREGROUND half of the plan.build fault seam: constructing
         # the plan (the background builder thread carries the other half)
         from . import faults as _faults
@@ -362,13 +391,6 @@ class TransformPlan:
             self._build_thread = threading.Thread(
                 target=self._build_compression_tables, daemon=True)
             self._build_thread.start()
-        # plan-build observability (spfft_tpu.obs): counters always, a
-        # compile-track span when tracing is on. The background
-        # compression-table build is NOT included — it overlaps the
-        # caller's next work by design and reports via its own scope.
-        from . import obs as _obs
-        _obs.record_plan_build(self, _time.perf_counter() - _t0_build,
-                               _t0_build)
 
     def _decide_pallas(self, use_pallas: Optional[bool]) -> bool:
         """Decide (cheaply, at construction) whether the Pallas
@@ -432,58 +454,62 @@ class TransformPlan:
         z-ascending (the layout the reference recommends,
         details.rst 'Data Distribution') is optimal, and a too-scattered
         order falls back to the XLA gather with a logged notice."""
-        from .ops import gather_kernel as gk
-        _t0_tables = _time.perf_counter()
+        from . import obs as _obs
         try:
-            from . import faults as _faults
-            _faults.check_site("plan.build")
-            p = self.index_plan
-            use_pallas = self._use_pallas_req
-            vi = p.value_indices.astype(np.int64)
-            num_slots = self._s_pad * p.dim_z
-            (dec_idx, occupied), (cmp_idx, cmp_valid) = \
-                gk.compression_gather_inputs(vi, num_slots)
-            dec = gk.build_best_gather_tables(dec_idx, occupied,
-                                              p.num_values)
-            # commit the first table set while the second builds on host
-            if dec is not None:
-                self._tables_hot["dec_tabs"] = gk.gather_device_tables(dec)
-            cmp_ = gk.build_best_gather_tables(cmp_idx, cmp_valid,
-                                               num_slots)
-            if cmp_ is not None:
-                self._tables_hot["cmp_tabs"] = gk.gather_device_tables(cmp_)
-            if dec is None or cmp_ is None:
-                from . import obs as _obs
-                fell_back = [n for n, t in (("decompress", dec),
-                                            ("compress", cmp_))
-                             if t is None]
-                for stage in fell_back:
-                    _obs.record_plan_fallback(stage, "value_order")
-                # WARNING only when the caller explicitly asked for the
-                # kernel; auto mode logs at INFO.
-                log = logger.warning if use_pallas is True else logger.info
-                log(
-                    "spfft_tpu: value order too scattered for the Pallas "
-                    "compression kernel (%s) — using the slower XLA gather "
-                    "path there (sort triplets with utils.workloads."
-                    "sort_triplets_stick_major for the fast path)",
-                    " and ".join(fell_back))
-            self._build_fused_tables(dec_idx, occupied, cmp_idx, cmp_valid,
-                                     num_slots, dec, cmp_)
-            if dec is None and cmp_ is None:
-                self._pallas_box = None
-                return
-            self._pallas_box = {"dec": dec, "cmp": cmp_}
-            self._pallas_active_flag = self._backend_ok
+            with _obs.compile_span(
+                    "compression_tables", parent=self._build_span,
+                    args={"num_values": int(self.index_plan.num_values)}
+                    ) as span:
+                # the first build in a process pays the import of the
+                # Pallas kernel modules here, a second or more
+                from .ops import gather_kernel as gk
+                from . import faults as _faults
+                _faults.check_site("plan.build")
+                p = self.index_plan
+                use_pallas = self._use_pallas_req
+                with _obs.compile_span("gather_tables", parent=span):
+                    vi = p.value_indices.astype(np.int64)
+                    num_slots = self._s_pad * p.dim_z
+                    (dec_idx, occupied), (cmp_idx, cmp_valid) = \
+                        gk.compression_gather_inputs(vi, num_slots)
+                    dec = gk.build_best_gather_tables(dec_idx, occupied,
+                                                      p.num_values)
+                    # commit the first table set while the second builds
+                    # on host
+                    if dec is not None:
+                        self._tables_hot["dec_tabs"] = \
+                            gk.gather_device_tables(dec)
+                    cmp_ = gk.build_best_gather_tables(cmp_idx, cmp_valid,
+                                                       num_slots)
+                    if cmp_ is not None:
+                        self._tables_hot["cmp_tabs"] = \
+                            gk.gather_device_tables(cmp_)
+                if dec is None or cmp_ is None:
+                    fell_back = [n for n, t in (("decompress", dec),
+                                                ("compress", cmp_))
+                                 if t is None]
+                    for stage in fell_back:
+                        _obs.record_plan_fallback(stage, "value_order")
+                    # WARNING only when the caller explicitly asked for
+                    # the kernel; auto mode logs at INFO.
+                    log = logger.warning if use_pallas is True \
+                        else logger.info
+                    log(
+                        "spfft_tpu: value order too scattered for the "
+                        "Pallas compression kernel (%s) — using the slower "
+                        "XLA gather path there (sort triplets with "
+                        "utils.workloads.sort_triplets_stick_major for the "
+                        "fast path)", " and ".join(fell_back))
+                with _obs.compile_span("fused_tables", parent=span):
+                    self._build_fused_tables(dec_idx, occupied, cmp_idx,
+                                             cmp_valid, num_slots, dec, cmp_)
+                if dec is None and cmp_ is None:
+                    self._pallas_box = None
+                    return
+                self._pallas_box = {"dec": dec, "cmp": cmp_}
+                self._pallas_active_flag = self._backend_ok
         except BaseException as exc:  # re-raised by _finalize
             self._build_exc = exc
-        finally:
-            from . import obs as _obs
-            _obs.record_compile(
-                "compression_tables",
-                _time.perf_counter() - _t0_tables, _t0_tables,
-                num_values=int(self.index_plan.num_values),
-                failed=self._build_exc is not None)
 
     def _build_fused_tables(self, dec_idx, occupied, cmp_idx, cmp_valid,
                             num_slots, dec_best, cmp_best) -> None:
@@ -679,7 +705,10 @@ class TransformPlan:
         :meth:`close`/``__del__`` use it for a silent teardown join."""
         th = self._build_thread
         if th is not None:
-            th.join()
+            if th.is_alive():
+                from . import obs as _obs
+                with _obs.compile_span("plan_join", parent=self._build_span):
+                    th.join()
             self._build_thread = None
             if self._build_exc is None:
                 box = self._pallas_box
@@ -790,12 +819,7 @@ class TransformPlan:
                 self._aot.pop("backward", None)
                 self._aot.pop("batched_backward", None)
         else:
-            self._forward_jit = {
-                Scaling.NONE: jax.jit(functools.partial(
-                    self._forward_impl, scaled=False)),
-                Scaling.FULL: jax.jit(functools.partial(
-                    self._forward_impl, scaled=True)),
-            }
+            self._forward_jit = self._scaled_jits(self._forward_impl)
             if self._aot is not None:
                 self._aot.pop("forward_none", None)
                 self._aot.pop("forward_full", None)
@@ -1111,26 +1135,29 @@ class TransformPlan:
         p = self.index_plan
         if not pallas or not self._pallas_active \
                 or self._pallas["dec"] is None:
-            if self._pair_io and values_il.shape[0] == 2:
-                values_il = values_il.T  # pair boundary -> rows, XLA path
-            return stages.decompress(values_il.astype(self._rdt),
-                                     tables["slot_src"], self._s_pad,
-                                     p.dim_z)
+            with jax.named_scope("spfft.layout"):
+                if self._pair_io and values_il.shape[0] == 2:
+                    values_il = values_il.T  # pair boundary -> rows
+                values_il = values_il.astype(self._rdt)
+            return stages.decompress(values_il, tables["slot_src"],
+                                     self._s_pad, p.dim_z)
         from .ops import gather_kernel as gk
         t = self._pallas["dec"]
         re, im = gk.planar_from_interleaved(values_il.astype(np.float32),
                                             t.src_rows, pair=self._pair_io)
         out_re, out_im = gk.run_gather(re, im, tables["dec_tabs"], t)
-        flat = (out_re.reshape(-1)[:t.num_out]
-                + 1j * out_im.reshape(-1)[:t.num_out])
-        return flat.reshape(self._s_pad, p.dim_z)
+        with jax.named_scope("spfft.gather"):
+            flat = (out_re.reshape(-1)[:t.num_out]
+                    + 1j * out_im.reshape(-1)[:t.num_out])
+            return flat.reshape(self._s_pad, p.dim_z)
 
     def _compress(self, sticks, tables, scale, pallas=True):
         p = self.index_plan
         if not pallas or not self._pallas_active \
                 or self._pallas["cmp"] is None:
             values = stages.compress(sticks, tables["value_indices"], scale)
-            return values.T if self._pair_io else values
+            with jax.named_scope("spfft.layout"):
+                return values.T if self._pair_io else values
         from .ops import gather_kernel as gk
         t = self._pallas["cmp"]
         re, im = gk.planar_from_complex(sticks, t.src_rows)
@@ -1138,7 +1165,8 @@ class TransformPlan:
         values = gk.interleaved_from_planar(out_re, out_im, t.num_out,
                                             pair=self._pair_io)
         if scale is not None:
-            values = values * jnp.asarray(scale, values.dtype)
+            with jax.named_scope("spfft.compress"):
+                values = values * jnp.asarray(scale, values.dtype)
         return values
 
     def _decompress_planar(self, values_il, tables, pallas=True):
@@ -1147,21 +1175,23 @@ class TransformPlan:
         p = self.index_plan
         if not pallas or not self._pallas_active \
                 or self._pallas["dec"] is None:
-            if self._pair_io and values_il.shape[0] == 2:
-                values_il = values_il.T
-            flat = stages.gather_rows_with_sentinel(
-                values_il.astype(self._rdt), tables["slot_src"])
-            return (flat[:, 0].reshape(self._s_pad, p.dim_z),
-                    flat[:, 1].reshape(self._s_pad, p.dim_z))
+            with jax.named_scope("spfft.decompress"):
+                if self._pair_io and values_il.shape[0] == 2:
+                    values_il = values_il.T
+                flat = stages.gather_rows_with_sentinel(
+                    values_il.astype(self._rdt), tables["slot_src"])
+                return (flat[:, 0].reshape(self._s_pad, p.dim_z),
+                        flat[:, 1].reshape(self._s_pad, p.dim_z))
         from .ops import gather_kernel as gk
         t = self._pallas["dec"]
         re, im = gk.planar_from_interleaved(values_il.astype(np.float32),
                                             t.src_rows, pair=self._pair_io)
         out_re, out_im = gk.run_gather(re, im, tables["dec_tabs"], t)
-        return (out_re.reshape(-1)[:t.num_out].reshape(self._s_pad,
-                                                       p.dim_z),
-                out_im.reshape(-1)[:t.num_out].reshape(self._s_pad,
-                                                       p.dim_z))
+        with jax.named_scope("spfft.gather"):
+            return (out_re.reshape(-1)[:t.num_out].reshape(self._s_pad,
+                                                           p.dim_z),
+                    out_im.reshape(-1)[:t.num_out].reshape(self._s_pad,
+                                                           p.dim_z))
 
     def _compress_planar(self, sr, si, tables, pallas=True):
         """PLANAR stick channels -> the plan's value output layout
@@ -1169,14 +1199,16 @@ class TransformPlan:
         p = self.index_plan
         if not pallas or not self._pallas_active \
                 or self._pallas["cmp"] is None:
-            flat = jnp.stack([sr.reshape(-1), si.reshape(-1)], axis=-1)
-            values = flat[tables["value_indices"]]
-            return values.T if self._pair_io else values
+            with jax.named_scope("spfft.compress"):
+                flat = jnp.stack([sr.reshape(-1), si.reshape(-1)], axis=-1)
+                values = flat[tables["value_indices"]]
+                return values.T if self._pair_io else values
         from .ops import gather_kernel as gk
         t = self._pallas["cmp"]
         pad = t.src_rows * 128 - sr.size
-        re = jnp.pad(sr.reshape(-1), (0, pad)).reshape(t.src_rows, 128)
-        im = jnp.pad(si.reshape(-1), (0, pad)).reshape(t.src_rows, 128)
+        with jax.named_scope("spfft.layout"):
+            re = jnp.pad(sr.reshape(-1), (0, pad)).reshape(t.src_rows, 128)
+            im = jnp.pad(si.reshape(-1), (0, pad)).reshape(t.src_rows, 128)
         out_re, out_im = gk.run_gather(re, im, tables["cmp_tabs"], t)
         return gk.interleaved_from_planar(out_re, out_im, t.num_out,
                                           pair=self._pair_io)
@@ -1195,9 +1227,10 @@ class TransformPlan:
             re, im, tables["fzd_tabs"], tables["fzd_mats"], t,
             interpret=not self._backend_ok)
         s = self._s_pad
-        if sr.ndim == 3:
-            return sr[:, :s], si[:, :s]
-        return sr[:s], si[:s]
+        with jax.named_scope("spfft.decompress_z"):
+            if sr.ndim == 3:
+                return sr[:, :s], si[:, :s]
+            return sr[:s], si[:s]
 
     def _zdft_compress(self, sr, si, tables, scaled: bool):
         """RAW planar stick channels -> the plan's value output layout
@@ -1248,12 +1281,16 @@ class TransformPlan:
         if self._is_r2c and p.zero_stick_id is not None:
             # complete the (0,0) stick: conj = im sign flip in planar form
             zid = p.zero_stick_id
-            rr, ri = sr[zid], si[zid]
-            nz = (rr != 0) | (ri != 0)
-            sr = sr.at[zid].set(jnp.where(nz, rr, jnp.roll(rr[::-1], 1)))
-            si = si.at[zid].set(jnp.where(nz, ri, -jnp.roll(ri[::-1], 1)))
-        sr, si = dft.pdft_last_opt(sr, si,
-                                   dft.c2c_mats(p.dim_z, dft.BACKWARD))
+            with jax.named_scope("spfft.stick_symmetry"):
+                rr, ri = sr[zid], si[zid]
+                nz = (rr != 0) | (ri != 0)
+                sr = sr.at[zid].set(
+                    jnp.where(nz, rr, jnp.roll(rr[::-1], 1)))
+                si = si.at[zid].set(
+                    jnp.where(nz, ri, -jnp.roll(ri[::-1], 1)))
+        with jax.named_scope("spfft.z"):
+            sr, si = dft.pdft_last_opt(sr, si,
+                                       dft.c2c_mats(p.dim_z, dft.BACKWARD))
         return self._backward_after_z(sr, si, tables)
 
     def _backward_after_z(self, sr, si, tables):
@@ -1278,12 +1315,13 @@ class TransformPlan:
         gi = unpack(si, col_tab, w, p.dim_y)
         if self._is_r2c and x0 == 0:
             # complete the x=0 sub-plane along y (contiguous in T layout)
-            cr, ci = gr[:, 0, :], gi[:, 0, :]
-            nz = (cr != 0) | (ci != 0)
-            gr = gr.at[:, 0, :].set(
-                jnp.where(nz, cr, jnp.roll(cr[:, ::-1], 1, axis=-1)))
-            gi = gi.at[:, 0, :].set(
-                jnp.where(nz, ci, -jnp.roll(ci[:, ::-1], 1, axis=-1)))
+            with jax.named_scope("spfft.plane_symmetry"):
+                cr, ci = gr[:, 0, :], gi[:, 0, :]
+                nz = (cr != 0) | (ci != 0)
+                gr = gr.at[:, 0, :].set(
+                    jnp.where(nz, cr, jnp.roll(cr[:, ::-1], 1, axis=-1)))
+                gi = gi.at[:, 0, :].set(
+                    jnp.where(nz, ci, -jnp.roll(ci[:, ::-1], 1, axis=-1)))
         y_mats = dft.c2c_mats(p.dim_y, dft.BACKWARD)
         if self._is_r2c:
             mats = dft.c2r_mats(p.dim_x) if rows is None \
@@ -1297,11 +1335,12 @@ class TransformPlan:
         """Complex-dtype wrapper of :meth:`_backward_rest_tp` (the batched
         path feeds complex sticks); returns the public interleaved (C2C)
         or real (R2C) space layout."""
-        out = self._backward_rest_tp(jnp.real(sticks), jnp.imag(sticks),
-                                     tables)
+        with jax.named_scope("spfft.layout"):
+            sr, si = jnp.real(sticks), jnp.imag(sticks)
+        out = self._backward_rest_tp(sr, si, tables)
         if self._is_r2c:
             return out
-        return jnp.stack([out[0], out[1]], axis=-1)
+        return self._space_interleaved(out)
 
     def _forward_head_tp(self, space_p, tables, scale):
         """Planar T-layout head of forward: x-stage on the minor axis,
@@ -1312,9 +1351,10 @@ class TransformPlan:
         from .ops import dft
         p = self.index_plan
         sr, si = self._forward_pre_z(space_p, tables)
-        return dft.pdft_last_opt(
-            sr, si, dft.c2c_mats(p.dim_z, dft.FORWARD,
-                                 scale=scale if scale else 1.0))
+        with jax.named_scope("spfft.z"):
+            return dft.pdft_last_opt(
+                sr, si, dft.c2c_mats(p.dim_z, dft.FORWARD,
+                                     scale=scale if scale else 1.0))
 
     def _forward_pre_z(self, space_p, tables):
         """The forward head UP TO the z-stage (xy stages + pack into
@@ -1354,9 +1394,10 @@ class TransformPlan:
     def _forward_head_t(self, space, tables, scale):
         """Complex-dtype wrapper of :meth:`_forward_head_tp` (batched
         path): interleaved/real space in, complex sticks out."""
-        sp = space if self._is_r2c else (space[..., 0], space[..., 1])
+        sp = space if self._is_r2c else self._space_planar(space)
         sr, si = self._forward_head_tp(sp, tables, scale)
-        return sr + 1j * si
+        with jax.named_scope("spfft.layout"):
+            return sr + 1j * si
 
     def _backward_rest(self, sticks, tables):
         """Everything after decompress: symmetry, z-IFFT, unpack, xy-IFFT."""
@@ -1378,13 +1419,15 @@ class TransformPlan:
                     sub = stages.complete_plane_hermitian(sub)
                 return stages.xy_backward_r2c_split(sub, x0, p.dim_x,
                                                     p.dim_x_freq)
-            return complex_to_interleaved(
-                stages.xy_backward_c2c_split(sub, x0, p.dim_x))
-        grid = unpack(sticks, tables["col_inv"], p.dim_y, p.dim_x_freq)
-        if self._is_r2c:
-            grid = stages.complete_plane_hermitian(grid)
-            return stages.xy_backward_r2c(grid, p.dim_x)
-        return complex_to_interleaved(stages.xy_backward_c2c(grid))
+            grid = stages.xy_backward_c2c_split(sub, x0, p.dim_x)
+        else:
+            grid = unpack(sticks, tables["col_inv"], p.dim_y, p.dim_x_freq)
+            if self._is_r2c:
+                grid = stages.complete_plane_hermitian(grid)
+                return stages.xy_backward_r2c(grid, p.dim_x)
+            grid = stages.xy_backward_c2c(grid)
+        with jax.named_scope("spfft.grid_io"):
+            return complex_to_interleaved(grid)
 
     # -- on-device double (double-single channels, ops/dsdft.py) ------------
     @staticmethod
@@ -1415,48 +1458,57 @@ class TransformPlan:
         (dim_z, dim_y, dim_x, 4) channel slab."""
         from .ops import dsdft
         p = self.index_plan
-        flat = stages.gather_rows_with_sentinel(values_il,
-                                                tables["slot_src"])
-        ch = tuple(flat[..., k].reshape(flat.shape[:-2]
-                                        + (p.num_sticks, p.dim_z))
-                   for k in range(4))
+        with jax.named_scope("spfft.decompress"):
+            flat = stages.gather_rows_with_sentinel(values_il,
+                                                    tables["slot_src"])
+            ch = tuple(flat[..., k].reshape(flat.shape[:-2]
+                                            + (p.num_sticks, p.dim_z))
+                       for k in range(4))
         if self._is_r2c and p.zero_stick_id is not None:
             # complete the (0,0) stick (conj reflection = sign flip on
             # the imaginary channels) — hi and lo transform identically
-            ch = self._ds_complete(ch, p.zero_stick_id)
-        ch = dsdft.ds_cdft_last(*ch, self._ds_mats["z_b"])
+            with jax.named_scope("spfft.stick_symmetry"):
+                ch = self._ds_complete(ch, p.zero_stick_id)
+        with jax.named_scope("spfft.z"):
+            ch = dsdft.ds_cdft_last(*ch, self._ds_mats["z_b"])
         ch = tuple(stages.sticks_to_grid(c, tables["col_inv_t"],
                                          p.dim_x_freq, p.dim_y)
                    for c in ch)
         if self._is_r2c:
             # complete the x=0 sub-plane along y (minor axis in T layout)
-            ch = self._ds_complete(ch, 0)
-        ch = dsdft.ds_cdft_last(*ch, self._ds_mats["y_b"])
-        ch = tuple(jnp.swapaxes(c, -1, -2) for c in ch)
-        if self._is_r2c:
-            oh, ol = dsdft.ds_irdft_last(*ch, self._ds_mats["x_b"])
-            return jnp.stack([oh, ol], axis=-1)
-        ch = dsdft.ds_cdft_last(*ch, self._ds_mats["x_b"])
-        return jnp.stack(ch, axis=-1)
+            with jax.named_scope("spfft.plane_symmetry"):
+                ch = self._ds_complete(ch, 0)
+        with jax.named_scope("spfft.xy"):
+            ch = dsdft.ds_cdft_last(*ch, self._ds_mats["y_b"])
+            ch = tuple(jnp.swapaxes(c, -1, -2) for c in ch)
+            if self._is_r2c:
+                ch = dsdft.ds_irdft_last(*ch, self._ds_mats["x_b"])
+            else:
+                ch = dsdft.ds_cdft_last(*ch, self._ds_mats["x_b"])
+        with jax.named_scope("spfft.grid_io"):
+            return jnp.stack(ch, axis=-1)
 
     def _ds_forward_impl(self, space4, tables, scaled: bool):
         """Forward mirror: (dim_z, dim_y, dim_x, 4) -> (N, 4), FULL
         scaling folded into the f64 z matrix before slicing."""
         from .ops import dsdft
-        if self._is_r2c:
-            ch = dsdft.ds_rdft_last(space4[..., 0], space4[..., 1],
-                                    self._ds_mats["x_f"])
-        else:
-            ch = dsdft.ds_cdft_last(*(space4[..., k] for k in range(4)),
-                                    self._ds_mats["x_f"])
-        ch = tuple(jnp.swapaxes(c, -1, -2) for c in ch)
-        ch = dsdft.ds_cdft_last(*ch, self._ds_mats["y_f"])
+        with jax.named_scope("spfft.grid_io"):
+            ch = tuple(space4[..., k] for k in range(space4.shape[-1]))
+        with jax.named_scope("spfft.xy"):
+            if self._is_r2c:
+                ch = dsdft.ds_rdft_last(*ch, self._ds_mats["x_f"])
+            else:
+                ch = dsdft.ds_cdft_last(*ch, self._ds_mats["x_f"])
+            ch = tuple(jnp.swapaxes(c, -1, -2) for c in ch)
+            ch = dsdft.ds_cdft_last(*ch, self._ds_mats["y_f"])
         ch = tuple(stages.grid_to_sticks(c, tables["scatter_cols_t"])
                    for c in ch)
-        ch = dsdft.ds_cdft_last(*ch,
-                                self._ds_mats["z_fs" if scaled else "z_f"])
-        flat = jnp.stack([c.reshape(-1) for c in ch], axis=-1)
-        return flat[tables["value_indices"]]
+        with jax.named_scope("spfft.z"):
+            ch = dsdft.ds_cdft_last(
+                *ch, self._ds_mats["z_fs" if scaled else "z_f"])
+        with jax.named_scope("spfft.compress"):
+            flat = jnp.stack([c.reshape(-1) for c in ch], axis=-1)
+            return flat[tables["value_indices"]]
 
     def _ds_space_to_host(self, out) -> np.ndarray:
         """Channel slab -> host f64: (…, 4) -> interleaved (…, 2), or
@@ -1471,6 +1523,21 @@ class TransformPlan:
 
     _ds_values_to_host = _ds_space_to_host  # same channel layout
 
+    @staticmethod
+    @jax.named_scope("spfft.grid_io")
+    def _space_interleaved(planar):
+        """Planar (re, im) C2C space -> the public interleaved
+        (..., 2) layout."""
+        return jnp.stack([planar[0], planar[1]], axis=-1)
+
+    @staticmethod
+    @jax.named_scope("spfft.grid_io")
+    def _space_planar(space):
+        """The public interleaved C2C space (..., 2) -> planar
+        (re, im)."""
+        return space[..., 0], space[..., 1]
+
+    @jax.named_scope("spfft.value_conj")
     def _apply_value_conj(self, values, *, batched=False):
         """Sign-flip the imaginary lane of the values folded from the
         redundant hermitian half (:attr:`IndexPlan.value_conj`): the
@@ -1497,7 +1564,7 @@ class TransformPlan:
             out = self._bwd_space_tp(values_il, tables, pallas)
             if self._is_r2c:
                 return out
-            return jnp.stack([out[0], out[1]], axis=-1)
+            return self._space_interleaved(out)
         return self._backward_rest(
             self._decompress(values_il, tables, pallas), tables)
 
@@ -1516,16 +1583,17 @@ class TransformPlan:
             else:
                 grid = stages.xy_forward_r2c(space.astype(self._rdt))
                 sticks = stages.grid_to_sticks(grid, tables["scatter_cols"])
-        elif self._split_x is not None:
-            x0, w = self._split_x
-            grid = stages.xy_forward_c2c_split(
-                interleaved_to_complex(space).astype(self._cdt), x0, w)
-            sticks = stages.grid_to_sticks(grid,
-                                           tables["scatter_cols_sub"])
         else:
-            grid = stages.xy_forward_c2c(
-                interleaved_to_complex(space).astype(self._cdt))
-            sticks = stages.grid_to_sticks(grid, tables["scatter_cols"])
+            with jax.named_scope("spfft.grid_io"):
+                space = interleaved_to_complex(space).astype(self._cdt)
+            if self._split_x is not None:
+                x0, w = self._split_x
+                grid = stages.xy_forward_c2c_split(space, x0, w)
+                sticks = stages.grid_to_sticks(grid,
+                                               tables["scatter_cols_sub"])
+            else:
+                grid = stages.xy_forward_c2c(space)
+                sticks = stages.grid_to_sticks(grid, tables["scatter_cols"])
         return stages.z_forward(sticks)
 
     def _forward_impl(self, space, tables, *, scaled: bool, pallas=True):
@@ -1533,7 +1601,7 @@ class TransformPlan:
             return self._apply_value_conj(
                 self._ds_forward_impl(space, tables, scaled))
         if self._use_mdft:  # planar pipeline, scale folded into z matrix
-            sp = space if self._is_r2c else (space[..., 0], space[..., 1])
+            sp = space if self._is_r2c else self._space_planar(space)
             return self._apply_value_conj(
                 self._fwd_values_tp(sp, tables, scaled, pallas))
         scale = 1.0 / self.global_size if scaled else None
@@ -1591,7 +1659,7 @@ class TransformPlan:
         out = self._backward_after_z(sr, si, tables)
         if self._is_r2c:
             return out
-        return jnp.stack([out[0], out[1]], axis=-1)
+        return self._space_interleaved(out)
 
     def _backward_impl_batched(self, values_b, tables):
         values_b = self._apply_value_conj(values_b, batched=True)
@@ -1616,7 +1684,7 @@ class TransformPlan:
         scale = 1.0 / self.global_size if scaled else None
         if self._use_mdft and self._fused_on("cmp"):
             sp_b = space_b if self._is_r2c \
-                else (space_b[..., 0], space_b[..., 1])
+                else self._space_planar(space_b)
             sr_b, si_b = jax.vmap(self._forward_pre_z,
                                   in_axes=(0, None))(sp_b, tables)
             return self._apply_value_conj(
@@ -1634,6 +1702,22 @@ class TransformPlan:
         return self._apply_value_conj(
             self._compress_batched(sticks_b, tables, scale), batched=True)
 
+    @staticmethod
+    def _scaled_jits(impl) -> dict:
+        """Scaling -> ``impl(arg, tables, scaled=...)`` jitted under a
+        program name of its own (see :func:`_entry`)."""
+        return {s: jax.jit(_entry(f"{impl.__name__}_{s.name.lower()}", impl,
+                                  scaled=s is Scaling.FULL))
+                for s in (Scaling.NONE, Scaling.FULL)}
+
+    def _pair_entry(self, fn, scaling: Scaling):
+        """The fused round trip ``(values, tables, *fn_args) -> values``
+        of :meth:`apply_pointwise`, not jitted, under the program name
+        ``_pair_impl_<scaling>``."""
+        scaling = Scaling(scaling)
+        return _entry(f"_pair_impl_{scaling.name.lower()}", self._pair_impl,
+                      scaled=scaling is Scaling.FULL, fn=fn)
+
     def _batched_jits(self):
         """Lazily-built batched executables over a leading batch axis.
 
@@ -1647,10 +1731,7 @@ class TransformPlan:
         if self._batched is None:
             self._batched = {
                 "backward": jax.jit(self._backward_impl_batched),
-                Scaling.NONE: jax.jit(functools.partial(
-                    self._forward_impl_batched, scaled=False)),
-                Scaling.FULL: jax.jit(functools.partial(
-                    self._forward_impl_batched, scaled=True)),
+                **self._scaled_jits(self._forward_impl_batched),
             }
         return self._batched
 
@@ -1711,20 +1792,21 @@ class TransformPlan:
         pair_values_io plans). Returns the (B, ...) stacked space-domain
         result in one fused execution. ``device`` pins the batch to one
         device of a pool (see :meth:`backward`)."""
-        per = ((self.index_plan.num_values, 4) if self._ds
-               else (2, self.index_plan.num_values) if self._pair_io
-               else (self.index_plan.num_values, 2))
-        if isinstance(values_batch, jax.Array) \
-                and values_batch.shape[1:] == per:
-            batch = values_batch
-        elif self._prestaged(values_batch, per):
-            # pre-staged host buffer (serving executor): one transfer,
-            # no per-row coercion
-            batch = jnp.asarray(values_batch)
-        else:
-            batch = self._stack_coerced(values_batch, self._coerce_values)
-        self._finalize()
         with timed_transform("backward_batched") as box:
+            per = ((self.index_plan.num_values, 4) if self._ds
+                   else (2, self.index_plan.num_values) if self._pair_io
+                   else (self.index_plan.num_values, 2))
+            if isinstance(values_batch, jax.Array) \
+                    and values_batch.shape[1:] == per:
+                batch = values_batch
+            elif self._prestaged(values_batch, per):
+                # pre-staged host buffer (serving executor): one
+                # transfer, no per-row coercion
+                batch = jnp.asarray(values_batch)
+            else:
+                batch = self._stack_coerced(values_batch,
+                                            self._coerce_values)
+            self._finalize()
             if device is not None:
                 batch = jax.device_put(batch, device)
             box.value = self._guarded(
@@ -1742,28 +1824,29 @@ class TransformPlan:
         (B, 2, num_values) for pair_values_io plans. ``device`` as in
         :meth:`backward`."""
         scaling = Scaling(scaling)
-        if self._ds:
-            # coerced DS slabs always carry a trailing channel axis:
-            # (B, z, y, x, 2) hi/lo for R2C, (B, z, y, x, 4) for C2C —
-            # a raw real R2C batch is also ndim 4, so the channel count
-            # must be checked, not just the rank
-            nch = 2 if self._is_r2c else 4
-            coerced = (isinstance(space_batch, jax.Array)
-                       and space_batch.ndim == 5
-                       and space_batch.shape[-1] == nch)
-        else:
-            coerced = (isinstance(space_batch, jax.Array)
-                       and space_batch.ndim
-                       == (4 if self._is_r2c else 5))
-        if coerced:
-            batch = space_batch
-        elif not self._ds and self._prestaged(
-                space_batch, self.batch_row_template("space")[0]):
-            batch = jnp.asarray(space_batch)
-        else:
-            batch = self._stack_coerced(space_batch, self._coerce_space)
-        self._finalize()
         with timed_transform("forward_batched") as box:
+            if self._ds:
+                # coerced DS slabs always carry a trailing channel axis:
+                # (B, z, y, x, 2) hi/lo for R2C, (B, z, y, x, 4) for C2C
+                # — a raw real R2C batch is also ndim 4, so the channel
+                # count must be checked, not just the rank
+                nch = 2 if self._is_r2c else 4
+                coerced = (isinstance(space_batch, jax.Array)
+                           and space_batch.ndim == 5
+                           and space_batch.shape[-1] == nch)
+            else:
+                coerced = (isinstance(space_batch, jax.Array)
+                           and space_batch.ndim
+                           == (4 if self._is_r2c else 5))
+            if coerced:
+                batch = space_batch
+            elif not self._ds and self._prestaged(
+                    space_batch, self.batch_row_template("space")[0]):
+                batch = jnp.asarray(space_batch)
+            else:
+                batch = self._stack_coerced(space_batch,
+                                            self._coerce_space)
+            self._finalize()
             if device is not None:
                 batch = jax.device_put(batch, device)
             box.value = self._guarded(
@@ -1797,9 +1880,8 @@ class TransformPlan:
                 if self._is_r2c:
                     space = fn(space, *fn_args)
                 else:
-                    s = fn(jnp.stack([space[0], space[1]], axis=-1),
-                           *fn_args)
-                    space = (s[..., 0], s[..., 1])
+                    space = self._space_planar(
+                        fn(self._space_interleaved(space), *fn_args))
             return self._apply_value_conj(
                 self._fwd_values_tp(space, tables, scaled))
         space = self._backward_impl(values_il, tables)
@@ -1839,17 +1921,16 @@ class TransformPlan:
                 "and silently break the double contract — compose "
                 "backward / fn on the host f64 slab / forward instead "
                 "(docs/precision.md)")
-        values_il = self._coerce_values(values)
-        key = (fn, scaling)
-        jitted = self._pair_jits.get(key)
-        if jitted is None:
-            jitted = jax.jit(
-                functools.partial(self._pair_impl,
-                                  scaled=scaling is Scaling.FULL, fn=fn),
-                donate_argnums=(0,) if self.donate_inputs else ())
-            self._pair_jits[key] = jitted
-        self._finalize()
         with timed_transform("apply_pointwise") as box:
+            values_il = self._coerce_values(values)
+            key = (fn, scaling)
+            jitted = self._pair_jits.get(key)
+            if jitted is None:
+                jitted = jax.jit(
+                    self._pair_entry(fn, scaling),
+                    donate_argnums=(0,) if self.donate_inputs else ())
+                self._pair_jits[key] = jitted
+            self._finalize()
             if fn is None and not fn_args:
                 # the identity pair is a store-exported AOT entry; fn
                 # captures are compile-time constants, so only the
@@ -1881,27 +1962,27 @@ class TransformPlan:
                 "(the pointwise fn would execute at f32) — loop "
                 "apply_pointwise / backward+forward instead "
                 "(docs/precision.md)")
-        # the scan carry dtype must match the step output (_rdt); coerce
-        # up-front rather than per step
-        values_il = self._coerce_values(values).astype(self._rdt)
-        key = (fn, scaling, int(steps), "scan")
-        jitted = self._pair_jits.get(key)
-        if jitted is None:
-            scaled = scaling is Scaling.FULL
-
-            def run(values_il, tables, *fn_args):
-                def step(v, _):
-                    return self._pair_impl(v, tables, *fn_args,
-                                           scaled=scaled, fn=fn), None
-                out, _ = jax.lax.scan(step, values_il, None,
-                                      length=int(steps))
-                return out
-
-            jitted = jax.jit(
-                run, donate_argnums=(0,) if self.donate_inputs else ())
-            self._pair_jits[key] = jitted
-        self._finalize()
         with timed_transform("iterate_pointwise") as box:
+            # the scan carry dtype must match the step output (_rdt);
+            # coerce up-front rather than per step
+            values_il = self._coerce_values(values).astype(self._rdt)
+            key = (fn, scaling, int(steps), "scan")
+            jitted = self._pair_jits.get(key)
+            if jitted is None:
+                pair = self._pair_entry(fn, scaling)
+
+                def iterate_pointwise(values_il, tables, *fn_args):
+                    def step(v, _):
+                        return pair(v, tables, *fn_args), None
+                    out, _ = jax.lax.scan(step, values_il, None,
+                                          length=int(steps))
+                    return out
+
+                jitted = jax.jit(
+                    iterate_pointwise,
+                    donate_argnums=(0,) if self.donate_inputs else ())
+                self._pair_jits[key] = jitted
+            self._finalize()
             box.value = jitted(values_il, self._tables_hot, *fn_args)
         return box.value
 
@@ -1916,9 +1997,9 @@ class TransformPlan:
         ``device`` pins the execution (input + replicated tables) to one
         device of a pool — the serving executor's cross-device
         round-robin; ``None`` keeps the default placement."""
-        values_il = self._coerce_values(values)
-        self._finalize()
         with timed_transform("backward") as box:
+            values_il = self._coerce_values(values)
+            self._finalize()
             if device is not None:
                 values_il = jax.device_put(values_il, device)
             box.value = self._guarded(
@@ -1959,9 +2040,9 @@ class TransformPlan:
         (details.rst "Normalization"). ``device`` as in
         :meth:`backward`."""
         scaling = Scaling(scaling)
-        space = self._coerce_space(space)
-        self._finalize()
         with timed_transform("forward") as box:
+            space = self._coerce_space(space)
+            self._finalize()
             if device is not None:
                 space = jax.device_put(space, device)
             key = "forward_full" if scaling is Scaling.FULL \
@@ -2111,8 +2192,11 @@ def make_local_plan(transform_type: TransformType, dim_x: int, dim_y: int,
     chosen precision cannot meet it, a typed
     :class:`~spfft_tpu.errors.PrecisionContractError` is raised instead
     of returning silently-degraded results."""
-    plan = build_index_plan(TransformType(transform_type), dim_x, dim_y,
-                            dim_z, np.asarray(triplets))
+    from . import obs as _obs
+    with _obs.compile_span("index_plan") as span:
+        plan = build_index_plan(TransformType(transform_type), dim_x, dim_y,
+                                dim_z, np.asarray(triplets))
     return TransformPlan(plan, precision=precision, use_pallas=use_pallas,
                          donate_inputs=donate_inputs,
-                         max_rel_error=max_rel_error)
+                         max_rel_error=max_rel_error,
+                         _trace_id=None if span is None else span.trace_id)

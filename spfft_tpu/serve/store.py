@@ -59,7 +59,6 @@ store-smoke``). See docs/artifact_cache.md.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import hashlib
 import io
 import json
@@ -439,10 +438,8 @@ def export_aot_blobs(plan: TransformPlan) -> Dict[str, bytes]:
     batched = plan._batched_jits()
     # un-donated pair jits: the store's copy must not inherit the
     # caller's donate_inputs buffer reuse
-    pair_none = jax.jit(functools.partial(plan._pair_impl, scaled=False,
-                                          fn=None))
-    pair_full = jax.jit(functools.partial(plan._pair_impl, scaled=True,
-                                          fn=None))
+    pair_none = jax.jit(plan._pair_entry(None, Scaling.NONE))
+    pair_full = jax.jit(plan._pair_entry(None, Scaling.FULL))
     b, = jax_export.symbolic_shape("b")
     entries = (
         ("backward", plan._backward_jit,

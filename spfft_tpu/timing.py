@@ -34,6 +34,8 @@ from typing import Any, Dict, List, Optional
 
 import jax
 
+from .obs import trace as _obs_trace
+
 
 class _Node:
     __slots__ = ("label", "times", "children")
@@ -225,18 +227,44 @@ class _ResultBox:
         self.value: Optional[Any] = None
 
 
-@contextlib.contextmanager
-def timed_transform(label: str):
-    """Scope for one transform execution: ``box.value = <result>`` inside the
-    scope makes the timing block on it."""
-    if not _enabled:
-        yield _ResultBox()
-        return
-    box = _ResultBox()
-    parent = GlobalTimer
-    with parent.scoped(label):
-        try:
-            yield box
-        finally:
-            if box.value is not None:
-                jax.block_until_ready(box.value)
+class timed_transform:
+    """The one instrumentation point of a public transform call, as a
+    context manager yielding a box: ``box.value = <result>`` inside it.
+
+    - With ``spfft_tpu.obs`` tracing on, the call is an
+      ``execute.<label>`` span (mirrored into the profiler, see
+      ``obs.Tracer.span``). It measures host time in the call: input
+      coercion, the wait for the plan's table build, the AOT or jit
+      dispatch. Not device time: the dispatch returns before the device
+      has run.
+    - With timing on (``enable()`` / ``SPFFT_TPU_TIMING=1``) the call is
+      a ``label`` scope of :data:`GlobalTimer`, which waits on
+      ``box.value`` before it closes, so it measures the device work too.
+    - With both off it reads two module-global booleans."""
+
+    __slots__ = ("label", "_box", "_span", "_scope")
+
+    def __init__(self, label: str):
+        self.label = label
+
+    def __enter__(self) -> _ResultBox:
+        self._box = box = _ResultBox()
+        self._scope = self._span = None
+        if _enabled:
+            self._scope = GlobalTimer.scoped(self.label)
+            self._scope.__enter__()
+        if _obs_trace._enabled:
+            self._span = _obs_trace.GLOBAL_TRACER.span(
+                "execute." + self.label, cat="execute", track="execute")
+            self._span.__enter__()
+        return box
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        if self._scope is not None:
+            try:
+                if self._box.value is not None:
+                    jax.block_until_ready(self._box.value)
+            finally:
+                self._scope.__exit__(exc_type, exc, tb)

@@ -65,19 +65,40 @@ def compile_for(one_chip):
     jax.config.update("jax_enable_x64", False)
     compilation_cache.reset_cache()
 
-    def shape(x):
-        return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype
-                                    if not hasattr(x, "dtype") else x.dtype,
-                                    sharding=one_chip)
-
     def run(fn, *args):
-        args = jax.tree_util.tree_map(shape, args)
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return _compile(jax.jit(fn), one_chip, args).as_text()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", was)
     jax.config.update("jax_enable_x64", was_x64)
     compilation_cache.reset_cache()
+
+
+def _compile(jitted, sharding, args):
+    """``jitted`` compiled with every array argument turned into a shape
+    on ``sharding``."""
+    def shape(x):
+        return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype
+                                    if not hasattr(x, "dtype") else x.dtype,
+                                    sharding=sharding)
+
+    args = jax.tree_util.tree_map(shape, args)
+    return jitted.lower(*args).compile()
+
+
+@pytest.fixture(scope="module")
+def compile_module(one_chip, compile_for):
+    """``compile_module(jitted, *args)``: the compiled HLO module proto
+    of an already jitted function (under ``compile_for``'s settings)."""
+    from benchmark import trace_reduce
+    _, hlo_pb2 = trace_reduce.protos()
+
+    def run(jitted, *args):
+        exe = _compile(jitted, one_chip, args).runtime_executable()
+        return hlo_pb2.HloModuleProto.FromString(
+            exe.hlo_modules()[0].as_serialized_hlo_module_proto())
+
+    return run
 
 
 @pytest.fixture(scope="module")
@@ -103,8 +124,8 @@ def plans(as_tpu, compile_for):
 
 
 def _pipeline(plan, which):
-    """(fn, args) of one public executable of ``plan``, args as host
-    arrays of the shapes the chip would see."""
+    """(jitted, args) of one public executable of ``plan`` as the plan
+    builds it, args as host arrays of the shapes the chip would see."""
     tabs = plan._tables_hot
     v = np.zeros((plan.index_plan.num_values, 2), np.float32)
     if which == "backward":
@@ -113,8 +134,8 @@ def _pipeline(plan, which):
     if which == "forward":
         return plan._forward_jit[Scaling.NONE], (space, tabs)
     if which == "pair":
-        return functools.partial(plan._pair_impl, scaled=False,
-                                 fn=None), (v, tabs)
+        # what apply_pointwise(values) jits and caches
+        return jax.jit(plan._pair_entry(None, Scaling.NONE)), (v, tabs)
     jits = plan._batched_jits()
     vb = np.zeros((2,) + v.shape, np.float32)
     if which == "batched_backward":
@@ -123,18 +144,73 @@ def _pipeline(plan, which):
     return jits[Scaling.NONE], (sb, tabs)
 
 
-@pytest.mark.parametrize("which", ["backward", "forward", "pair",
-                                   "batched_backward", "batched_forward"])
+@pytest.fixture(scope="module")
+def compiled(plans, compile_module):
+    """``compiled(kind, which)``: the compiled HLO module proto of one
+    public executable, compiled once for the module's tests."""
+    cache = {}
+
+    def get(kind, which):
+        if (kind, which) not in cache:
+            plan = plans[kind]
+            assert plan._pallas_active
+            assert (plan._fused["dec"] is not None
+                    and plan._fused["cmp"] is not None)
+            fn, args = _pipeline(plan, which)
+            cache[kind, which] = compile_module(fn, *args)
+        return cache[kind, which]
+
+    return get
+
+
+PIPELINES = pytest.mark.parametrize(
+    "which", ["backward", "forward", "pair", "batched_backward",
+              "batched_forward"])
+
+
+@PIPELINES
 @pytest.mark.parametrize("kind", ["c2c", "r2c"])
-def test_pipeline_256_compiles(plans, compile_for, kind, which):
+def test_pipeline_256_compiles(compiled, kind, which):
     """The whole executable, gather + fused kernels included, as the
     chip runs it: both fused directions must be active, and the
     installed compiler must accept the program."""
-    plan = plans[kind]
-    assert plan._pallas_active
-    assert plan._fused["dec"] is not None and plan._fused["cmp"] is not None
-    fn, args = _pipeline(plan, which)
-    assert "tpu_custom_call" in compile_for(fn, *args)
+    module = compiled(kind, which)
+    assert any(inst.custom_call_target == "tpu_custom_call"
+               for comp in module.computations
+               for inst in comp.instructions)
+
+
+@PIPELINES
+@pytest.mark.parametrize("kind", ["c2c", "r2c"])
+def test_pipeline_256_stage_scopes(compiled, kind, which):
+    """Every operation of the executable carries the ``spfft.*`` stage
+    scope that the benchmark's trace reduction reads: the program has a
+    name of its own, every instruction whose ``op_name`` comes from a
+    traced operation holds a scope (an ``op_name`` without ``/`` names
+    an argument), and where the instruction's stack frames pass through
+    a stage entry point of ``benchmark/stages.json``, the scope gives
+    the stage those frames give."""
+    from benchmark import trace_reduce
+    rules = trace_reduce.load_stage_rules()
+    module = compiled(kind, which)
+    assert module.name != "jit__unknown"
+    insts = trace_reduce.hlo_instructions(module)
+    unscoped, differ, framed = [], [], 0
+    for name, (op_name, frames) in insts.items():
+        if "/" not in op_name:
+            continue
+        scopes = rules["scope"].findall(op_name)
+        if not scopes:
+            unscoped.append((name, op_name))
+            continue
+        by_frames = trace_reduce.stage_of("", frames, rules)
+        if by_frames is not None:
+            framed += 1
+            if by_frames != scopes[-1]:
+                differ.append((name, op_name, by_frames))
+    assert not unscoped
+    assert not differ
+    assert framed  # the compiled proto keeps the stack frames
 
 
 def _gather_inputs(plan):
