@@ -186,11 +186,12 @@ def wide_gather_tables(idx: np.ndarray, valid: np.ndarray, *,
     executable specification and the fallback).
 
     Returns ``(row0, sub, out_tile, first, packed, kp, K, max_row0)`` or
-    None if the native library is unavailable / P != 8; raises
+    None if the native library is unavailable or ``p_tiles`` is not a
+    multiple of 4 in [4, 64]; raises
     :class:`WideCoverBlowup` on a chunk-count blowup exactly where the
     NumPy builder returns None — the caller maps that to its fallback."""
     lib = _load()
-    if lib is None or p_tiles != 8:
+    if lib is None or p_tiles % 4 or not 4 <= p_tiles <= 64:
         return None
     idx64 = np.ascontiguousarray(idx, np.int64)
     val8 = np.ascontiguousarray(valid, np.uint8)

@@ -145,7 +145,7 @@ int32_t spfft_tpu_inverse_map(const int32_t* indices, int64_t n,
 // The NumPy builder is the executable specification; this native version
 // exists because the vectorised multi-round cover makes ~20 full passes
 // over (G_s, P, 1024) arrays (122 s at 512^3 — 105M slots). Here every
-// super-tile is covered independently (sequential rounds over its 8192
+// super-tile is covered independently (sequential rounds over its P * 1024
 // slots), parallel over super-tiles: one pass, cache-resident.
 //
 // Geometry and cover semantics replicate the Python builder EXACTLY (the
@@ -160,6 +160,11 @@ namespace {
 constexpr int kTile = 1024;
 constexpr int kLane = 128;
 constexpr int32_t kBig = INT32_C(1) << 30;
+// Most tiles per super-tile (fused_kernel.MAX_P_TILES): the per-tile
+// cover state below lives on the stack, 64 KiB of it at this bound.
+constexpr int kMaxP = 64;
+
+bool bad_p(int32_t P) { return P < 4 || P > kMaxP || P % 4 != 0; }
 
 struct WideGeom {
   int P;
@@ -181,7 +186,7 @@ int64_t cover_super_tile(const int64_t* idx_p, const uint8_t* valid_p,
   const int P = g.P, kp = g.kp, K = g.K;
   const int64_t s0 = st * P * kTile;
   // Uncovered = valid (invalid slots never need covering).
-  bool uncovered[8][kTile];  // P <= 8 enforced at the ABI
+  bool uncovered[kMaxP][kTile];  // P <= kMaxP enforced at the ABI
   bool any_unc = false;
   for (int p = 0; p < P; ++p)
     for (int t = 0; t < kTile; ++t) {
@@ -194,8 +199,8 @@ int64_t cover_super_tile(const int64_t* idx_p, const uint8_t* valid_p,
     if (round > 0 && !any_unc) break;
     if (c >= limit) return limit + 1;  // caller treats as blowup
     // base[p] = min uncovered row
-    int32_t base[8];
-    bool hasu[8];
+    int32_t base[kMaxP];
+    bool hasu[kMaxP];
     int32_t r0 = kBig;
     for (int p = 0; p < P; ++p) {
       int32_t b = kBig;
@@ -210,8 +215,8 @@ int64_t cover_super_tile(const int64_t* idx_p, const uint8_t* valid_p,
     }
     if (r0 == kBig) r0 = 0;
     if (r0 > g.r_clamp) r0 = g.r_clamp;
-    bool inwin[8];
-    int32_t basec[8];
+    bool inwin[kMaxP];
+    int32_t basec[kMaxP];
     for (int p = 0; p < P; ++p) {
       // sub-window saturates at the window top so tail rows stay
       // coverable when r0 is clamped (see r_clamp)
@@ -277,8 +282,8 @@ extern "C" {
 
 // Phase 1: choose geometry + count chunks.
 //
-// idx[L] int64 (any order), valid[L] uint8; P must be 8 and a multiple of
-// 4. kp_in / k_in force the sub-window / DMA-window heights (0 = choose
+// idx[L] int64 (any order), valid[L] uint8; P a multiple of 4 in
+// [4, kMaxP]. kp_in / k_in force the sub-window / DMA-window heights (0 = choose
 // from the data, replicating the Python cost model / quantile). On
 // success writes kp/K/C and returns 0; returns -1 when the cover exceeds
 // the blowup limit (caller falls back), -2 on invalid arguments.
@@ -287,7 +292,7 @@ int32_t spfft_tpu_wide_tables_plan(const int64_t* idx, const uint8_t* valid,
                                    int32_t kp_in, int32_t k_in,
                                    int32_t* kp_out, int32_t* k_out,
                                    int64_t* c_out) {
-  if (L <= 0 || P != 8) return -2;
+  if (L <= 0 || bad_p(P)) return -2;
   const int64_t SUPER = int64_t(P) * kTile;
   const int64_t G_s = (L + SUPER - 1) / SUPER;
   const int64_t Lp = G_s * SUPER;
@@ -415,7 +420,7 @@ int32_t spfft_tpu_wide_tables_fill(const int64_t* idx, const uint8_t* valid,
                                    int32_t* sub, int32_t* out_tile,
                                    int32_t* first, int16_t* packed,
                                    int32_t* max_row0_out) {
-  if (L <= 0 || P != 8) return -2;
+  if (L <= 0 || bad_p(P)) return -2;
   const int64_t SUPER = int64_t(P) * kTile;
   const int64_t G_s = (L + SUPER - 1) / SUPER;
   const int64_t Lp = G_s * SUPER;

@@ -69,7 +69,8 @@ __all__ = [
     "MetricsServer", "METRICS_PORT_ENV", "port_from_env",
     "compile_span",
     "record_compile", "record_plan_build", "record_exchange_plan",
-    "record_hlo_counts", "record_plan_fallback", "record_store",
+    "record_hlo_counts", "record_plan_fallback", "record_fused_geometry",
+    "record_store",
     "record_store_aot_skip",
     # flight recorder (obs.recorder)
     "EVENT_SPECS", "GLOBAL_JOURNAL", "BUNDLE_VERSION",
@@ -135,6 +136,16 @@ def record_plan_fallback(stage: str, reason: str) -> None:
         GLOBAL_TRACER.instant("plan.pallas_fallback", cat="compile",
                               track="compile",
                               args={"stage": stage, "reason": reason})
+
+
+def record_fused_geometry(direction: str, geometry: str) -> None:
+    """The kernel geometry one fused compression+z-DFT direction of a
+    plan got (``super_tile`` or ``narrow``, ops/fused_kernel.py):
+    ``spfft_plan_fused_geometry_total`` by {direction, geometry}."""
+    GLOBAL_COUNTERS.inc("spfft_plan_fused_geometry_total", 1,
+                        help="Fused z-kernel geometry chosen per plan "
+                             "direction.",
+                        direction=direction, geometry=geometry)
 
 
 def _count_compile(kind: str, seconds: float) -> None:

@@ -46,8 +46,14 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
         ("counter",
          "Plan-time Pallas fallback decisions by stage and reason. "
          "Stages: decompress, compress, fused_decompress_zdft, "
-         "fused_zdft_compress, dist_fused_decompress_zdft, "
-         "dist_fused_zdft_compress."),
+         "fused_zdft_compress, fused_super_tile_backward, "
+         "fused_super_tile_forward (narrow fused kernel kept), "
+         "dist_fused_decompress_zdft, dist_fused_zdft_compress."),
+    "spfft_plan_fused_geometry_total":
+        ("counter",
+         "Fused z-kernel geometry each fused plan direction got, "
+         "labelled {direction=backward|forward, "
+         "geometry=super_tile|narrow}."),
     # distributed exchange accounting
     "spfft_exchange_plans_total":
         ("counter", "Distributed plans constructed."),

@@ -979,6 +979,8 @@ class DistributedTransformPlan:
                            "t1": len(stacked_all) + len(stacked),
                            "n_sticks": s1 - s0})
             stacked_all.extend(stacked)
+        # the shape-uniform per-shard tables are the narrow decomposition
+        _obs.record_fused_geometry("backward", "narrow")
         self._fused_dist = {
             "chunks": chunks, "stacked": stacked_all,
             "n_tabs": len(stacked_all), "src_rows": src_rows,
@@ -1119,6 +1121,7 @@ class DistributedTransformPlan:
         # the fused forward is bit-identical to the unfused
         # z_forward+gather+scale path (folding the scale into the
         # matrix values would not be).
+        _obs.record_fused_geometry("forward", "narrow")
         self._fused_dist_fwd = {
             "rep": rep, "stacked": stacked, "n_tabs": len(stacked),
             "mats": fkm.commit_mats(_dft.c2c_mats(dim_z, _dft.FORWARD)),

@@ -500,9 +500,11 @@ class TransformPlan:
                         "XLA gather path there (sort triplets with "
                         "utils.workloads.sort_triplets_stick_major for the "
                         "fast path)", " and ".join(fell_back))
-                with _obs.compile_span("fused_tables", parent=span):
+                with _obs.compile_span("fused_tables",
+                                       parent=span) as fspan:
                     self._build_fused_tables(dec_idx, occupied, cmp_idx,
-                                             cmp_valid, num_slots, dec, cmp_)
+                                             cmp_valid, num_slots, dec, cmp_,
+                                             span=fspan)
                 if dec is None and cmp_ is None:
                     self._pallas_box = None
                     return
@@ -512,17 +514,21 @@ class TransformPlan:
             self._build_exc = exc
 
     def _build_fused_tables(self, dec_idx, occupied, cmp_idx, cmp_valid,
-                            num_slots, dec_best, cmp_best) -> None:
+                            num_slots, dec_best, cmp_best, span=None) -> None:
         """Build the fused compression+z-DFT tables (ops/fused_kernel)
         for whichever directions pass the gate; record every decline as
         a ``spfft_plan_pallas_fallback_total`` reason. Runs on the
         background build thread, after the gather tables.
 
-        The fused kernels consume the NARROW chunk decomposition
-        (chunks of one 1024-slot tile, tile-major — the revisiting
-        order the super-tile accumulation needs); when the preferred
-        gather tables came out wide, a narrow set is built here just
-        for the fused path."""
+        Each direction takes the ``super_tile`` geometry (one grid step
+        per super-tile, tables from the wide gather cover) where its
+        tables fit, else the ``narrow`` one (one step per 1024-slot
+        tile, from the narrow decomposition: the preferred gather
+        tables when they came out narrow, else a narrow set built here),
+        with the reason recorded under the ``fused_super_tile_*``
+        stages. ``spfft_plan_fused_geometry_total`` counts what each
+        direction got, and ``span`` (the ``compile.fused_tables`` span,
+        None with tracing off) carries it with the grid steps."""
         from . import obs as _obs
         from .ops import dft as _dft
         from .ops import fused_kernel as fkm
@@ -547,40 +553,67 @@ class TransformPlan:
         # and rides INSIDE the kernel (ops/fused_kernel
         # ._complete_zero_stick), so r2c plans take the fused path too.
         zid = p.zero_stick_id if self._is_r2c else None
-        nt = narrow(dec_best, dec_idx, occupied, p.num_values)
-        if nt is None:
-            reasons["dec"] = "value_order"
-        else:
-            out = fkm.build_fused_decompress_tables(
+
+        def narrow_dec():
+            nt = narrow(dec_best, dec_idx, occupied, p.num_values)
+            if nt is None:
+                return "value_order"
+            return fkm.build_fused_decompress_tables(
                 nt, p.dim_z, self._s_pad, zero_stick_id=zid)
-            if isinstance(out, str):
-                reasons["dec"] = out
-            else:
-                box["dec"] = out
-                self._tables_hot["fzd_tabs"] = \
-                    fkm.decompress_device_tables(out)
-                self._tables_hot["fzd_mats"] = fkm.commit_mats(
-                    _dft.c2c_mats(p.dim_z, _dft.BACKWARD))
+
         # forward twin: z-DFT + compress gather, FULL scaling folded
         # into a second matrix triple at plan time
-        ct = narrow(cmp_best, cmp_idx, cmp_valid, num_slots)
-        if ct is None:
-            reasons["cmp"] = "value_order"
-        else:
-            out = fkm.build_fused_compress_tables(ct, p.dim_z, self._s_pad)
-            if isinstance(out, str):
-                reasons["cmp"] = out
+        def narrow_cmp():
+            ct = narrow(cmp_best, cmp_idx, cmp_valid, num_slots)
+            if ct is None:
+                return "value_order"
+            return fkm.build_fused_compress_tables(ct, p.dim_z, self._s_pad)
+
+        stage_name = {"dec": "fused_decompress_zdft",
+                      "cmp": "fused_zdft_compress"}
+        attrs = {}
+        for which, direction in (("dec", "backward"), ("cmp", "forward")):
+            if which == "dec":
+                tabs = fkm.build_super_decompress_tables(
+                    dec_idx, occupied, p.num_values, p.dim_z, p.num_sticks,
+                    zero_stick_id=zid)
             else:
-                box["cmp"] = out
+                tabs = fkm.build_super_compress_tables(
+                    cmp_idx, cmp_valid, num_slots, p.dim_z, self._s_pad)
+            why_super = None
+            if isinstance(tabs, str):
+                why_super = tabs
+                tabs = narrow_dec() if which == "dec" else narrow_cmp()
+            if isinstance(tabs, str):
+                reasons[which] = tabs
+                continue
+            box[which] = tabs
+            if why_super is not None:
+                _obs.record_plan_fallback(f"fused_super_tile_{direction}",
+                                          why_super)
+                logger.info(
+                    "spfft_tpu: super-tile tables do not fit %s (%s) — "
+                    "keeping the narrow fused kernel there",
+                    stage_name[which], why_super)
+            _obs.record_fused_geometry(direction, tabs.geometry)
+            attrs[f"{direction}_geometry"] = tabs.geometry
+            attrs[f"{direction}_grid_steps"] = tabs.grid_steps
+            # commit each direction's tables while the next one builds
+            if which == "dec":
+                self._tables_hot["fzd_tabs"] = \
+                    fkm.decompress_device_tables(tabs)
+                self._tables_hot["fzd_mats"] = fkm.commit_mats(
+                    _dft.c2c_mats(p.dim_z, _dft.BACKWARD))
+            else:
                 self._tables_hot["fzc_tabs"] = \
-                    fkm.compress_device_tables(out)
+                    fkm.compress_device_tables(tabs)
                 self._tables_hot["fzc_mats"] = fkm.commit_mats(
                     _dft.c2c_mats(p.dim_z, _dft.FORWARD))
                 self._tables_hot["fzc_mats_s"] = fkm.commit_mats(
                     _dft.c2c_mats(p.dim_z, _dft.FORWARD,
                                   scale=1.0 / float(self.global_size)))
-        stage_name = {"dec": "fused_decompress_zdft",
-                      "cmp": "fused_zdft_compress"}
+        if span is not None:
+            span.args = {**(span.args or {}), **attrs}
         for which, why in reasons.items():
             _obs.record_plan_fallback(stage_name[which], why)
             logger.info(
@@ -1064,6 +1097,27 @@ class TransformPlan:
         return self._fused_active_flag
 
     @property
+    def fused_geometry(self) -> dict:
+        """Kernel geometry of each fused direction (``"backward"`` /
+        ``"forward"``): ``"super_tile"`` (one grid step per super-tile
+        of whole sticks) or ``"narrow"`` (one per 1024-slot tile);
+        directions that are not fused are absent."""
+        self._finalize()
+        return {d: t.geometry for d, t in self._fused_directions()}
+
+    @property
+    def fused_grid_steps(self) -> dict:
+        """Grid steps of each fused direction's kernel per transform
+        (per field of a batched call), keyed like :attr:`fused_geometry`."""
+        self._finalize()
+        return {d: t.grid_steps for d, t in self._fused_directions()}
+
+    def _fused_directions(self):
+        for which, d in (("dec", "backward"), ("cmp", "forward")):
+            if self._fused_box.get(which) is not None:
+                yield d, self._fused_box[which]
+
+    @property
     def fused_fallback_reasons(self) -> dict:
         """Per-direction fallback reasons of the fused
         compression+z-DFT gate: ``{"dec": reason, "cmp": reason}`` with
@@ -1228,9 +1282,13 @@ class TransformPlan:
             interpret=not self._backend_ok)
         s = self._s_pad
         with jax.named_scope("spfft.decompress_z"):
-            if sr.ndim == 3:
-                return sr[:, :s], si[:, :s]
-            return sr[:s], si[:s]
+            rows = sr.shape[-2]
+            if rows < s:
+                # super-tile tables end at the last real stick; the
+                # unpack's sentinel reads the zero rows past it
+                pad = [(0, 0)] * (sr.ndim - 2) + [(0, s - rows), (0, 0)]
+                return jnp.pad(sr, pad), jnp.pad(si, pad)
+            return sr[..., :s, :], si[..., :s, :]
 
     def _zdft_compress(self, sr, si, tables, scaled: bool):
         """RAW planar stick channels -> the plan's value output layout

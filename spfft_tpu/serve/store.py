@@ -122,7 +122,9 @@ FORMAT_VERSION = 1
 #: (gather_kernel.*GatherTables, fused_kernel.Fused*Tables) change
 #: fields — an old artifact then rejects cleanly instead of
 #: reconstructing garbage.
-TABLE_SCHEMA = 2  # 2: FusedDecompressTables.zinfo (r2c completion)
+TABLE_SCHEMA = 3  # 2: FusedDecompressTables.zinfo (r2c completion);
+                  # 3: fused tables carry the super-tile geometry
+                  # (toff / kp_rows / p_tiles; pos and off optional)
 
 MANIFEST_KEY = "spfft_tpu_plan_manifest"
 MANIFEST_VERSION = 1

@@ -143,6 +143,207 @@ def test_super_tile_geometry_invariant():
         assert p <= fkm.MAX_P_TILES
 
 
+# -- super-tile geometry vs the narrow fused kernel and a dense oracle ------
+
+GEOM_N = 12  # grid 12 x 12 x DIM_Z: super-tiles of 64 sticks, 8 tiles
+
+
+def _geometry_triplets(sticks: str, r2c: bool):
+    """``cutoff``: SpFFT's benchmark set (every stick with x below half
+    the x columns, full z, in stick order); ``sphere``: a ball of
+    partial sticks; ``shuffled``: the cutoff sticks in a random stick
+    order, so a super-tile's values lie in several source windows."""
+    n, h = GEOM_N, GEOM_N // 2
+    if sticks == "sphere":
+        xs = range(0, h + 1) if r2c else range(-h + 1, h)
+        trip = [(x % n, y % n, z % DIM_Z)
+                for x in xs for y in range(-h + 1, h)
+                for z in range(-DIM_Z // 2, DIM_Z // 2)
+                if (x / h) ** 2 + (y / h) ** 2
+                + (z / (DIM_Z / 2)) ** 2 <= 1.0]
+        return np.asarray(trip, np.int32)
+    xs = range(h + 1 if r2c else h)
+    trip = np.asarray([(x, y, z) for x in xs for y in range(n)
+                       for z in range(DIM_Z)], np.int32)
+    if sticks == "shuffled":
+        st = trip.reshape(-1, DIM_Z, 3)
+        perm = np.random.default_rng(31).permutation(len(st))
+        trip = st[perm].reshape(-1, 3)
+    return trip
+
+
+def _geometry_case(sticks: str, ttype: str):
+    """Index plan and both geometries' fused tables for one stick set,
+    built as the plan builds them."""
+    from spfft_tpu.indexing import build_index_plan
+    r2c = ttype == "r2c"
+    p = build_index_plan(TransformType.R2C if r2c else TransformType.C2C,
+                         GEOM_N, GEOM_N, DIM_Z,
+                         _geometry_triplets(sticks, r2c))
+    s_pad = -(-(p.num_sticks + 1) // 32) * 32
+    num_slots = s_pad * DIM_Z
+    (dec_idx, occ), (cmp_idx, cmp_valid) = gk.compression_gather_inputs(
+        p.value_indices.astype(np.int64), num_slots)
+    zid = p.zero_stick_id if r2c else None
+    dec = (fkm.build_super_decompress_tables(
+               dec_idx, occ, p.num_values, DIM_Z, p.num_sticks,
+               zero_stick_id=zid),
+           fkm.build_fused_decompress_tables(
+               gk.build_monotone_gather_tables(dec_idx, occ,
+                                               p.num_values),
+               DIM_Z, s_pad, zero_stick_id=zid))
+    cmp_ = (fkm.build_super_compress_tables(cmp_idx, cmp_valid, num_slots,
+                                            DIM_Z, s_pad),
+            fkm.build_fused_compress_tables(
+                gk.build_monotone_gather_tables(cmp_idx, cmp_valid,
+                                                num_slots),
+                DIM_Z, s_pad))
+    for sup, nar in (dec, cmp_):
+        assert sup.geometry == "super_tile" and nar.geometry == "narrow"
+    if sticks == "shuffled":
+        assert dec[0].grid_steps > dec[0].num_super
+        assert cmp_[0].grid_steps > cmp_[0].num_tiles // cmp_[0].p_tiles
+    return p, s_pad, zid, dec, cmp_
+
+
+def _rel_l2(a, b):
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+GEOMETRY_CASES = [
+    pytest.param(sticks, ttype, batch, id=f"{sticks}-{ttype}-b{batch}")
+    for sticks in ("cutoff", "sphere", "shuffled")
+    for ttype in ("c2c", "r2c") for batch in (0, 2)]
+
+
+@pytest.mark.parametrize("sticks,ttype,batch", GEOMETRY_CASES)
+def test_super_tile_backward_matches_narrow(sticks, ttype, batch):
+    """The super-tile backward kernel is bit-identical to the narrow
+    one (same (R, dim_z) z-DFT per super-tile, each slot receiving its
+    one value) and matches a dense float64 oracle, the r2c (0,0)-stick
+    completion included."""
+    p, _, zid, (sup, nar), _ = _geometry_case(sticks, ttype)
+    lead = (batch,) if batch else ()
+    vals = np.random.default_rng(32).standard_normal(
+        lead + (p.num_values, 2)).astype(np.float32)
+    mats = fkm.commit_mats(dft.c2c_mats(DIM_Z, dft.BACKWARD))
+
+    def run(t):
+        re, im = gk.planar_from_interleaved(jnp.asarray(vals), t.src_rows)
+        sr, si = fkm.run_decompress_zdft(
+            re, im, fkm.decompress_device_tables(t), mats, t,
+            interpret=True)
+        return (np.asarray(sr)[..., :p.num_sticks, :],
+                np.asarray(si)[..., :p.num_sticks, :])
+
+    got, want = run(sup), run(nar)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+
+    x = np.zeros(lead + (p.num_sticks * DIM_Z,), np.complex128)
+    x[..., p.value_indices] = vals[..., 0] + 1j * vals[..., 1]
+    x = x.reshape(lead + (p.num_sticks, DIM_Z))
+    if zid is not None:
+        row = x[..., zid, :]
+        mirror = np.conj(np.roll(row[..., ::-1], 1, axis=-1))
+        x[..., zid, :] = np.where(row != 0, row, mirror)
+    oracle = np.fft.ifft(x, axis=-1) * DIM_Z
+    assert _rel_l2(got[0] + 1j * got[1], oracle) < 1e-6
+
+
+@pytest.mark.parametrize("sticks,ttype,batch", GEOMETRY_CASES)
+def test_super_tile_forward_matches_narrow(sticks, ttype, batch):
+    """The super-tile forward kernel (P output tiles a step from one
+    transformed stick window) agrees with the narrow one to 2e-7
+    relative l2 and with a dense float64 oracle."""
+    p, s_pad, _, _, (sup, nar) = _geometry_case(sticks, ttype)
+    lead = (batch,) if batch else ()
+    rng = np.random.default_rng(33)
+    raw = np.zeros(lead + (2, s_pad, DIM_Z), np.float32)
+    raw[..., :p.num_sticks, :] = rng.standard_normal(
+        lead + (2, p.num_sticks, DIM_Z))
+    sr, si = jnp.asarray(raw[..., 0, :, :]), jnp.asarray(raw[..., 1, :, :])
+    mats = fkm.commit_mats(dft.c2c_mats(DIM_Z, dft.FORWARD))
+
+    def run(t):
+        psr, psi = fkm.pad_sticks_planar(sr, si, t.src_sticks)
+        o_re, o_im = fkm.run_zdft_compress(
+            psr, psi, fkm.compress_device_tables(t), mats, t,
+            interpret=True)
+        flat = lead + (-1,)
+        return (np.asarray(o_re).reshape(flat)[..., :t.num_out]
+                + 1j * np.asarray(o_im).reshape(flat)[..., :t.num_out])
+
+    got, want = run(sup), run(nar)
+    assert _rel_l2(got, want) <= 2e-7
+    x = raw[..., 0, :, :].astype(np.float64) + 1j * raw[..., 1, :, :]
+    y = np.fft.fft(x, axis=-1).reshape(lead + (-1,))
+    assert _rel_l2(got, y[..., p.value_indices]) < 1e-6
+
+
+def test_super_tile_geometry_counted(fused_env):
+    """SpFFT's cutoff stick set (the benchmark cells' kind) takes the
+    super-tile geometry in both directions: one grid step per 64-stick
+    super-tile, recorded in ``spfft_plan_fused_geometry_total`` and on
+    the plan."""
+    from spfft_tpu import obs
+    from spfft_tpu.benchmark import cutoff_stick_triplets
+
+    def count(direction, geometry):
+        return obs.GLOBAL_COUNTERS.get("spfft_plan_fused_geometry_total",
+                                       direction=direction,
+                                       geometry=geometry)
+
+    before = {d: count(d, "super_tile") for d in ("backward", "forward")}
+    trip = cutoff_stick_triplets(16, 16, DIM_Z, 0.5, False)
+    plan = _plan(trip, nx=16, ny=16)
+    assert plan.fused_active and plan.fused_fallback_reasons == {}
+    assert plan.fused_geometry == {"backward": "super_tile",
+                                   "forward": "super_tile"}
+    # 128 full sticks: 2 super-tiles a direction
+    assert plan.fused_grid_steps == {"backward": 2, "forward": 2}
+    for d in ("backward", "forward"):
+        assert count(d, "super_tile") == before[d] + 1
+    vals = _values(len(trip), seed=34)
+    space = np.asarray(plan.backward(vals))
+    np.testing.assert_array_equal(space, _unfused_backward(plan, vals))
+    out = np.asarray(plan.forward(space, Scaling.FULL))
+    np.testing.assert_allclose(out, _unfused_forward(plan, space, True),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_super_tile_smem_limit_keeps_narrow(fused_env, monkeypatch):
+    """Tables past the scalar-prefetch budget keep the narrow fused
+    kernel — never the unfused path — with the reason recorded under
+    the ``fused_super_tile_*`` stages and ``narrow`` counted."""
+    from spfft_tpu import obs
+    monkeypatch.setattr(fkm, "SMEM_TABLE_WORDS", 8)
+
+    def count(name, **labels):
+        return obs.GLOBAL_COUNTERS.get(name, **labels)
+
+    dirs = ("backward", "forward")
+    before = {d: (count("spfft_plan_fused_geometry_total", direction=d,
+                        geometry="narrow"),
+                  count("spfft_plan_pallas_fallback_total",
+                        stage=f"fused_super_tile_{d}", reason="smem"))
+              for d in dirs}
+    plan = _plan(_gappy_triplets())
+    assert plan.fused_active and plan.fused_fallback_reasons == {}
+    assert plan.fused_geometry == {"backward": "narrow",
+                                   "forward": "narrow"}
+    for d in dirs:
+        assert count("spfft_plan_fused_geometry_total", direction=d,
+                     geometry="narrow") == before[d][0] + 1
+        assert count("spfft_plan_pallas_fallback_total",
+                     stage=f"fused_super_tile_{d}",
+                     reason="smem") == before[d][1] + 1
+    vals = _values(plan.num_local_elements, seed=35)
+    space = np.asarray(plan.backward(vals))
+    np.testing.assert_allclose(space, _unfused_backward(plan, vals),
+                               rtol=2e-6, atol=2e-6)
+
+
 # -- plan level --------------------------------------------------------------
 
 def test_plan_backward_forward_fused_bit_exact(fused_env):

@@ -140,6 +140,17 @@ def test_native_wide_tables_parity(monkeypatch):
     """The C++ wide-gather cover produces IDENTICAL tables to the NumPy
     builder (the executable specification) — geometry choice, chunk order,
     packed words, byte-packed sub offsets, everything."""
+    _check_wide_parity(monkeypatch, p_tiles=8)
+
+
+@pytest.mark.parametrize("p_tiles", [16, 64])
+def test_native_wide_tables_parity_super_tile(monkeypatch, p_tiles):
+    """The same parity at the fused z kernels' super-tile widths (16
+    tiles at dim_z 256, 64 the most the fused geometry takes)."""
+    _check_wide_parity(monkeypatch, p_tiles=p_tiles)
+
+
+def _check_wide_parity(monkeypatch, p_tiles):
     from spfft_tpu import native
     from spfft_tpu.ops import gather_kernel as gk
 
@@ -164,6 +175,7 @@ def test_native_wide_tables_parity(monkeypatch):
                {"kp_rows": 16, "k_rows": 128})
 
     for name, idx, valid, num_src, kw in cases():
+        kw = dict(kw, p_tiles=p_tiles)
         t_nat = gk.build_wide_gather_tables(idx, valid, num_src, **kw)
         with monkeypatch.context() as m:
             m.setattr(native, "wide_gather_tables",

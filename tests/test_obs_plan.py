@@ -99,6 +99,28 @@ def test_plan_build_is_one_trace_with_parent_links(fresh, annotations):
     plan.close()
 
 
+def test_fused_tables_span_carries_geometry(fresh, monkeypatch):
+    """``compile.fused_tables`` carries each fused direction's kernel
+    geometry and grid steps per transform, as the plan reports them,
+    and ``spfft_plan_fused_geometry_total`` counts them."""
+    monkeypatch.setenv("SPFFT_TPU_FORCE_MATMUL_DFT", "1")
+    monkeypatch.setenv("SPFFT_TPU_FUSED_INTERPRET", "1")
+    tr = np.asarray([(x, y, z) for x in range(4) for y in range(4)
+                     for z in range(0, 128, 2)], np.int32)
+    plan = make_local_plan(TransformType.C2C, 4, 4, 128, tr,
+                           precision="single", use_pallas=True)
+    plan.check_build(wait=True)
+    span = {s.name: s for s in _spans()}["compile.fused_tables"]
+    steps = plan.fused_grid_steps
+    for d in ("backward", "forward"):
+        assert plan.fused_geometry[d] == "super_tile"
+        assert span.args[f"{d}_geometry"] == "super_tile"
+        assert span.args[f"{d}_grid_steps"] == steps[d] >= 1
+        assert fresh.get("spfft_plan_fused_geometry_total", direction=d,
+                         geometry="super_tile") == 1
+    plan.close()
+
+
 def test_plan_join_span_covers_the_wait(fresh, monkeypatch):
     """A caller that reaches the table build while it still runs waits
     in a ``compile.plan_join`` span under the plan build."""

@@ -322,6 +322,50 @@ def test_table_schema_mismatch_rejected(tmp_path):
     assert _reject_count(s2, "version_mismatch") == 1
 
 
+def test_table_schema_of_narrow_fused_layout_rejected(tmp_path):
+    """An artifact written before the fused tables carried the
+    super-tile geometry (table schema 2) is refused as a version
+    mismatch and rebuilt, never loaded."""
+    store, reg, tr, sig, plan = _build_store(tmp_path)
+    path = store.artifact_path(signature_key(sig))
+    header, payload = _split_artifact(path)
+    header["table_schema"] = 2
+    _rewrite(path, header, payload)
+    s2 = PlanArtifactStore(store.root)
+    assert s2.load_signature(sig) is None
+    assert _reject_count(s2, "version_mismatch") == 1
+
+
+def test_fused_super_tile_tables_roundtrip(tmp_path, monkeypatch):
+    """The fused kernels' super-tile tables survive the artifact: the
+    restored plan runs the same geometry from the stored tables and
+    stays bit-exact."""
+    monkeypatch.setenv("SPFFT_TPU_FORCE_MATMUL_DFT", "1")
+    monkeypatch.setenv("SPFFT_TPU_FUSED_INTERPRET", "1")
+    nz = 128
+    tr = np.asarray([(x, y, z) for x in range(4) for y in range(4)
+                     for z in range(0, nz, 2)], np.int32)
+    store = PlanArtifactStore(str(tmp_path / "store"))
+    sig, plan = PlanRegistry(store=store).get_or_build(
+        TransformType.C2C, 4, 4, nz, tr, use_pallas=True)
+    store.drain()
+    assert plan.fused_geometry == {"backward": "super_tile",
+                                   "forward": "super_tile"}
+    vals = _values(plan)
+    want = np.asarray(plan.backward(vals))
+    got = PlanArtifactStore(store.root).load_signature(
+        sig, plan_kwargs={"use_pallas": True})
+    assert got is not None
+    _, plan2 = got
+    assert plan2._build_thread is None
+    assert plan2.fused_geometry == plan.fused_geometry
+    for which in ("dec", "cmp"):
+        a, b = plan._fused[which], plan2._fused[which]
+        np.testing.assert_array_equal(a.toff, b.toff)
+        assert a.kp_rows == b.kp_rows and a.p_tiles == b.p_tiles
+    assert np.array_equal(np.asarray(plan2.backward(vals)), want)
+
+
 def test_stale_index_digest_rejected(tmp_path):
     """A payload whose checksum is VALID but whose tables no longer
     digest to the signature they claim (the hand-edited/stale-artifact

@@ -326,3 +326,152 @@ def test_wide_launch_at_chunk_limit(compile_for):
             interpret=False),
         src, src, *tabs)
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def cell_tables():
+    """Super-tile fused tables of the benchmark cells' stick sets, built
+    as the plan builds them: SpFFT's cutoff sets at 256^3, C2C at
+    sparsity 0.5 (32768 full sticks) and R2C at 0.1 (3328)."""
+    from spfft_tpu.benchmark import cutoff_stick_triplets
+    from spfft_tpu.indexing import build_index_plan
+    out = {}
+    for kind, tt, sparsity in (("c2c", TransformType.C2C, 0.5),
+                               ("r2c", TransformType.R2C, 0.1)):
+        tr = cutoff_stick_triplets(N, N, N, sparsity, kind == "r2c")
+        p = build_index_plan(tt, N, N, N, tr)
+        s_pad = -(-(p.num_sticks + 1) // 32) * 32
+        num_slots = s_pad * N
+        (dec_idx, occ), (cmp_idx, cmp_valid) = gk.compression_gather_inputs(
+            p.value_indices.astype(np.int64), num_slots)
+        zid = p.zero_stick_id if kind == "r2c" else None
+        out[kind] = (
+            fkm.build_super_decompress_tables(
+                dec_idx, occ, p.num_values, N, p.num_sticks,
+                zero_stick_id=zid),
+            fkm.build_super_compress_tables(cmp_idx, cmp_valid, num_slots,
+                                            N, s_pad))
+    return out
+
+
+@pytest.fixture
+def grids(monkeypatch):
+    """The grids of the ``pallas_call``s traced while the test runs."""
+    seen = []
+    real = fkm.pl.pallas_call
+
+    def record(*args, **kw):
+        seen.append(tuple(kw["grid_spec"].grid))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(fkm.pl, "pallas_call", record)
+    return seen
+
+
+CELLS = pytest.mark.parametrize("kind,steps,batch",
+                                [("c2c", 512, 0), ("r2c", 52, 0),
+                                 ("r2c", 52, 32)])
+
+
+@CELLS
+def test_super_tile_decompress_cells_compile(cell_tables, compile_for,
+                                             grids, kind, steps, batch):
+    """The backward fused kernel at the cells' super-tile geometry: one
+    grid step per 64-stick super-tile (512 for C2C, 52 a field for R2C,
+    against 8200 and 840 one-tile steps before), within the VMEM budget,
+    accepted by the installed compiler."""
+    t = cell_tables[kind][0]
+    assert t.geometry == "super_tile" and t.grid_steps == steps
+    assert (t.r_sticks, t.p_tiles) == (64, 16)
+    assert fkm._fits_backward(N, t.p_tiles, t.span_rows,
+                              t.zinfo is not None, t.p_tiles)
+    lead = (batch,) if batch else ()
+    src = np.zeros(lead + (t.src_rows, gk.TILE_LANE), np.float32)
+    dev = tuple(np.asarray(a) for a in fkm.decompress_device_tables(t))
+    mats = tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(N, dft.BACKWARD))
+    text = compile_for(
+        lambda re, im, d, m: fkm.run_decompress_zdft(re, im, d, m, t),
+        src, src, dev, mats)
+    assert "tpu_custom_call" in text
+    assert grids == [lead + (steps,)]
+
+
+@CELLS
+def test_super_tile_compress_cells_compile(cell_tables, compile_for, grids,
+                                           kind, steps, batch):
+    """The forward fused kernel at the cells' geometry: one grid step per
+    16 output tiles, each opening one 64-stick window (no stick is
+    transformed twice), within the VMEM budget."""
+    t = cell_tables[kind][1]
+    assert t.geometry == "super_tile" and t.grid_steps == steps
+    assert t.win_sticks == 64 and int(t.wnew.sum()) == steps
+    assert fkm._fits_forward(N, t.win_sticks, t.p_tiles)
+    lead = (batch,) if batch else ()
+    sticks = np.zeros(lead + (t.src_sticks, N), np.float32)
+    dev = tuple(np.asarray(a) for a in fkm.compress_device_tables(t))
+    mats = tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(N, dft.FORWARD))
+    text = compile_for(
+        lambda sr, si, d, m: fkm.run_zdft_compress(sr, si, d, m, t),
+        sticks, sticks, dev, mats)
+    assert "tpu_custom_call" in text
+    assert grids == [lead + (steps,)]
+
+
+def test_super_tile_launch_at_smem_limit(compile_for):
+    """The super-tile forward kernel compiles with as many chunks as
+    ``SMEM_TABLE_WORDS`` admits at ``dim_z`` 256 (16 tiles a chunk): its
+    1-D scalar-prefetch tables stay in the 1 MiB SMEM, which 16,000
+    chunks overflow."""
+    P = 16
+    C = fkm.SMEM_TABLE_WORDS // (5 + P)
+    i32 = functools.partial(np.zeros, dtype=np.int32)
+    t = fkm.FusedCompressTables(
+        s0=i32(C), out_tile=np.arange(C, dtype=np.int32),
+        first=np.ones(C, np.int32), wnew=np.ones(C, np.int32),
+        packed=np.zeros((C, P * 8, gk.TILE_LANE), np.int16), dim_z=N,
+        win_sticks=64, num_tiles=C * P, num_out=C * P * gk.TILE,
+        src_sticks=C * 64, span_rows=128, toff=i32(C * P), p_tiles=P,
+        kp_rows=8)
+    sticks = np.zeros((t.src_sticks, N), np.float32)
+    dev = (t.s0, t.toff, t.out_tile, t.first, t.wnew, t.packed)
+    mats = tuple(np.asarray(m, np.float32)
+                 for m in dft.c2c_mats(N, dft.FORWARD))
+    text = compile_for(
+        lambda sr, si, d, m: fkm.run_zdft_compress(sr, si, d, m, t),
+        sticks, sticks, dev, mats)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [0, 2])
+@pytest.mark.parametrize("direction", ["dec", "cmp"])
+def test_narrow_fused_256_compiles(plans, compile_for, direction, batch):
+    """The narrow geometry, which the distributed plan and any tables
+    past the super-tile budget keep, on the 256^3 C2C sphere's real
+    tables."""
+    plan = plans["c2c"]
+    num_slots, ((dec_idx, occ), (cmp_idx, cmp_valid)) = \
+        _gather_inputs(plan)
+    lead = (batch,) if batch else ()
+    s_pad = plan._s_pad
+    if direction == "dec":
+        nt = gk.build_monotone_gather_tables(dec_idx, occ,
+                                             plan.index_plan.num_values)
+        t = fkm.build_fused_decompress_tables(nt, N, s_pad)
+        src = np.zeros(lead + (t.src_rows, gk.TILE_LANE), np.float32)
+        dev = tuple(np.asarray(a) for a in fkm.decompress_device_tables(t))
+        run = fkm.run_decompress_zdft
+        mats = dft.c2c_mats(N, dft.BACKWARD)
+    else:
+        nt = gk.build_monotone_gather_tables(cmp_idx, cmp_valid, num_slots)
+        t = fkm.build_fused_compress_tables(nt, N, s_pad)
+        src = np.zeros(lead + (t.src_sticks, N), np.float32)
+        dev = tuple(np.asarray(a) for a in fkm.compress_device_tables(t))
+        run = fkm.run_zdft_compress
+        mats = dft.c2c_mats(N, dft.FORWARD)
+    assert t.geometry == "narrow"
+    mats = tuple(np.asarray(m, np.float32) for m in mats)
+    text = compile_for(lambda a, b, d, m: run(a, b, d, m, t),
+                       src, src, dev, mats)
+    assert "tpu_custom_call" in text
