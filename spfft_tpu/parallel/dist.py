@@ -38,7 +38,9 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
 import jax
@@ -81,6 +83,19 @@ WIRE_PRECISION_ENV = "SPFFT_TPU_WIRE_PRECISION"
 WIRE_ERROR_BUDGET_ENV = "SPFFT_TPU_WIRE_ERROR_BUDGET"
 
 logger = logging.getLogger("spfft_tpu")
+
+
+def _in_parallel(fn, items) -> list:
+    """``[fn(item) for item in items]`` with one thread an item: the
+    per-shard plan builds (and the two directions' gather tables) are
+    independent, and their NumPy and native planner work runs outside
+    the GIL."""
+    items = list(items)
+    if len(items) < 2:
+        return [fn(item) for item in items]
+    workers = min(len(items), os.cpu_count() or 1)
+    with ThreadPoolExecutor(workers) as pool:
+        return list(pool.map(fn, items))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,11 +172,13 @@ def build_distributed_plan(transform_type: TransformType,
         # reference: parameters.cpp:107-109 (MPIParameterMismatchError)
         raise ParameterMismatchError(
             f"sum of planes per shard ({sum(planes)}) != dim_z ({dim_z})")
-    shard_plans = tuple(
-        build_index_plan(transform_type, dim_x, dim_y, dim_z,
-                         np.asarray(t).reshape(-1, 3))
-        for t in triplets_per_shard)
-    check_stick_duplicates([p.stick_keys for p in shard_plans])
+    from .. import obs as _obs
+    with _obs.compile_span("index_plan"):
+        shard_plans = tuple(_in_parallel(
+            lambda t: build_index_plan(transform_type, dim_x, dim_y, dim_z,
+                                       np.asarray(t).reshape(-1, 3)),
+            triplets_per_shard))
+        check_stick_duplicates([p.stick_keys for p in shard_plans])
     total_sticks = sum(p.num_sticks for p in shard_plans)
     if total_sticks > dim_x * dim_y:
         # reference: parameters.cpp:103-106
@@ -188,6 +205,7 @@ class DistributedTransformPlan:
                  overlap_chunks: Optional[int] = None,
                  wire_precision: Optional[int] = None,
                  wire_error_budget: Optional[float] = None):
+        from .. import obs as _obs
         from ..utils.platform import enable_persistent_compilation_cache
         enable_persistent_compilation_cache()
         _t0_build = time.perf_counter()
@@ -284,27 +302,27 @@ class DistributedTransformPlan:
             stick_align = _fkm.super_tile_geometry(dist_plan.dim_z)[0]
         use_ppermute_compact = _os.environ.get(
             "SPFFT_TPU_COMPACT_PPERMUTE") == "1"
-        if self.exchange.compact:
-            if dist_plan.num_shards > 1 and not use_ppermute_compact:
-                if k_eff > 1:
+        with _obs.compile_span("exchange_schedule"):
+            if self.exchange.compact:
+                if dist_plan.num_shards > 1 and not use_ppermute_compact:
+                    if k_eff > 1:
+                        self._overlap = build_overlap_schedule(
+                            dist_plan, k_eff, "ragged",
+                            x_window=self._split_x,
+                            stick_align=stick_align)
+                    else:
+                        self._ragged = build_ragged_schedule(
+                            dist_plan, x_window=self._split_x)
+                elif k_eff > 1 and dist_plan.num_shards > 1:
                     self._overlap = build_overlap_schedule(
-                        dist_plan, k_eff, "ragged",
-                        x_window=self._split_x,
-                        stick_align=stick_align)
+                        dist_plan, k_eff, "compact",
+                        x_window=self._split_x, stick_align=stick_align)
                 else:
-                    self._ragged = build_ragged_schedule(
+                    self._compact = build_compact_schedule(
                         dist_plan, x_window=self._split_x)
-            elif k_eff > 1 and dist_plan.num_shards > 1:
+            elif k_eff > 1:
                 self._overlap = build_overlap_schedule(
-                    dist_plan, k_eff, "compact", x_window=self._split_x,
-                    stick_align=stick_align)
-            else:
-                self._compact = build_compact_schedule(
-                    dist_plan, x_window=self._split_x)
-        elif k_eff > 1:
-            self._overlap = build_overlap_schedule(dist_plan, k_eff,
-                                                   "block",
-                                                   stick_align=stick_align)
+                    dist_plan, k_eff, "block", stick_align=stick_align)
         # SPFFT_TPU_FORCE_RAGGED_OP=1 lowers the REAL ragged op off-TPU
         # (XLA:CPU can lower it but not execute it) — used by the HLO
         # launch-count checks in tests and scripts/scaling_model.py.
@@ -324,8 +342,10 @@ class DistributedTransformPlan:
         # block layout, and the measured probe must run BEFORE the
         # comm-size-1 local delegation so every plan records its rung.
         self._resolve_wire_rung(wire_precision, wire_error_budget)
-        self._build_tables()
-        self._init_pallas(use_pallas)
+        with _obs.compile_span("exchange_tables"):
+            self._build_tables()
+        with _obs.compile_span("gather_tables"):
+            self._init_pallas(use_pallas)
         self._sharded = NamedSharding(self.mesh, P(self.axis_name))
         self._replicated = NamedSharding(self.mesh, P())
         # Commit the static tables to device once, at plan time (never on the
@@ -376,8 +396,9 @@ class DistributedTransformPlan:
         # forward z-DFT+compress. Tables appended LAST — backward set
         # then forward set — so the bodies keep slicing
         # ptables/ctables by the existing counts.
-        self._init_fused_dist(use_pallas)
-        self._init_fused_dist_fwd(use_pallas)
+        with _obs.compile_span("fused_tables"):
+            self._init_fused_dist(use_pallas)
+            self._init_fused_dist_fwd(use_pallas)
         self._n_fb = 0
         self._n_ff = 0
         fused_specs = ()
@@ -453,7 +474,6 @@ class DistributedTransformPlan:
         # the exact wire/busiest-link byte accounting — per chunk when
         # the overlap pipeline is active — surfaced as metrics so
         # distributed rounds stop hand-rolling them into bench JSON
-        from .. import obs as _obs
         _dt = time.perf_counter() - _t0_build
         _obs.record_plan_build(self, _dt, _t0_build)
         _obs.record_exchange_plan(self, _dt, _t0_build)
@@ -726,9 +746,10 @@ class DistributedTransformPlan:
         if mv == 0 or num_slots == 0:
             return
 
-        per_shard = [gk.compression_gather_inputs(
-            p.value_indices, num_slots, pad_values_to=mv)
-            for p in dp.shard_plans]
+        per_shard = _in_parallel(
+            lambda p: gk.compression_gather_inputs(
+                p.value_indices, num_slots, pad_values_to=mv),
+            dp.shard_plans)
 
         def build_uniform(which, num_src, num_out, builder, pad_fn,
                           geom_keys, extra):
@@ -737,18 +758,23 @@ class DistributedTransformPlan:
             forced so the SPMD program is uniform; pad chunk counts to the
             max and stack. Returns None if any shard declines (caller
             falls through to the next kind / the XLA path)."""
-            tables = [builder(idx, valid, num_src, allow_segments=False)
-                      for (idx, valid) in (s[which] for s in per_shard)]
+            tables = _in_parallel(
+                lambda s: builder(*s[which], num_src, allow_segments=False),
+                per_shard)
             if any(t is None for t in tables):
                 return None
             forced = {kw: max(getattr(t, attr) for t in tables)
                       for attr, kw in geom_keys.items()}
-            tables = [t if all(getattr(t, a) == forced[kw]
-                               for a, kw in geom_keys.items()) else
-                      builder(per_shard[r][which][0],
-                              per_shard[r][which][1], num_src,
-                              allow_segments=False, **forced)
-                      for r, t in enumerate(tables)]
+
+            def uniform(r):
+                t = tables[r]
+                if all(getattr(t, a) == forced[kw]
+                       for a, kw in geom_keys.items()):
+                    return t
+                return builder(*per_shard[r][which], num_src,
+                               allow_segments=False, **forced)
+
+            tables = _in_parallel(uniform, range(len(tables)))
             if any(t is None for t in tables):
                 return None  # a forced rebuild crossed the chunk ceiling
             c_max = max(t.row0.shape[0] for t in tables)
@@ -764,23 +790,27 @@ class DistributedTransformPlan:
         def build_all(which, num_src, num_out):
             # num_super / num_tiles are identical across shards already
             # (the idx length is the padded uniform max_values /
-            # max_sticks * dim_z on every shard).
-            return build_uniform(
+            # max_sticks * dim_z on every shard). A wide cover takes at
+            # least one chunk a super-tile, and the stacked tables cannot
+            # split a launch: past the chunk limit it cannot fit.
+            supers = -(-num_out // (gk.WIDE_P * gk.TILE))
+            wide = supers <= gk.WIDE_SEG_CHUNK_LIMIT and build_uniform(
                 which, num_src, num_out, gk.build_wide_gather_tables,
                 gk.pad_wide_tables_to,
                 {"kp_rows": "kp_rows", "span_rows": "k_rows"},
                 lambda t0: {"kind": "wide", "kp": t0.kp_rows,
                             "p_tiles": t0.p_tiles,
-                            "super_p1": t0.num_super + 1},
-            ) or build_uniform(
+                            "super_p1": t0.num_super + 1})
+            return wide or build_uniform(
                 which, num_src, num_out, gk.build_monotone_gather_tables,
                 gk.pad_tables_to, {"span_rows": "k_rows"},
                 lambda t0: {"kind": "narrow",
                             "tiles_p1": t0.num_tiles + 1},
             )
 
-        dec = build_all(0, num_src=mv, num_out=num_slots)
-        cmp_ = build_all(1, num_src=num_slots, num_out=mv)
+        # the two directions are independent too
+        dec, cmp_ = _in_parallel(lambda args: build_all(*args),
+                                [(0, mv, num_slots), (1, num_slots, mv)])
         if dec is None or cmp_ is None:
             logger.warning(
                 "spfft_tpu: a shard's value order is too scattered for the "
@@ -795,6 +825,7 @@ class DistributedTransformPlan:
         }
         self._pallas_interpret = not backend_ok
 
+    @jax.named_scope("spfft.gather")
     def _pallas_gather(self, flat_il, t, tables):
         """Run the windowed gather (wide or narrow kernel) on one shard's
         (N, 2) interleaved data."""
@@ -1012,8 +1043,9 @@ class DistributedTransformPlan:
             sr, si = fkm.run_decompress_zdft(re, im, dev, mats, ch["rep"],
                                              interpret=fd["interpret"])
             n = ch["n_sticks"]
-            out.append((sr[..., :n, :]
-                        + 1j * si[..., :n, :]).astype(self._cdt))
+            with jax.named_scope("spfft.decompress_z"):
+                out.append((sr[..., :n, :]
+                            + 1j * si[..., :n, :]).astype(self._cdt))
         return out
 
     def _fused_dec_zdft_shard(self, vals, xtables):
@@ -1144,18 +1176,23 @@ class DistributedTransformPlan:
         seg = xtables[base:base + self._n_ff]
         dev = tuple(a[0] for a in seg[:ff["n_tabs"]])  # drop the shard axis
         mats = seg[ff["n_tabs"]:ff["n_tabs"] + 3]      # replicated, as-is
-        sr = jnp.real(sticks).astype(jnp.float32)
-        si = jnp.imag(sticks).astype(jnp.float32)
+        with jax.named_scope("spfft.layout"):
+            sr = jnp.real(sticks).astype(jnp.float32)
+            si = jnp.imag(sticks).astype(jnp.float32)
         sr, si = fkm.pad_sticks_planar(sr, si, rep.src_sticks)
         out_re, out_im = fkm.run_zdft_compress(sr, si, dev, mats, rep,
                                                interpret=ff["interpret"])
         values = gk.interleaved_from_planar(out_re, out_im, rep.num_out)
-        if scaled:
-            values = values * jnp.asarray(1.0 / self.global_size,
-                                          self._rdt)
-        return values.astype(self._rdt)
+        with jax.named_scope("spfft.z_compress"):
+            if scaled:
+                values = values * jnp.asarray(1.0 / self.global_size,
+                                              self._rdt)
+            return values.astype(self._rdt)
 
     # -- SPMD bodies ---------------------------------------------------------
+    # The exchange's own methods are its layout work, the collectives
+    # inside carry ``spfft.exchange`` (parallel/exchange.py).
+    @jax.named_scope("spfft.exchange_layout")
     def _exchange_freq_to_grid(self, sticks, zmap, col_inv, ctables):
         """z-sticks -> local plane grid across the mesh, via the selected
         exchange mechanism."""
@@ -1199,6 +1236,7 @@ class DistributedTransformPlan:
         return unpack_blocks_to_grid(blocks, col_inv, dp.dim_y,
                                      self._xf_eff)
 
+    @jax.named_scope("spfft.exchange_layout")
     def _exchange_grid_to_sticks(self, grid, cols_flat, z_src, ctables):
         """Local plane grid -> z-sticks across the mesh (forward mirror)."""
         _faults.check_site("exchange.collective")  # trace time: per compile
@@ -1235,6 +1273,9 @@ class DistributedTransformPlan:
         return unpack_blocks_to_sticks(blocks, z_src)
 
     # -- chunk-pipelined exchange (compute/communication overlap) -----------
+    # (the chunk slices and late unpacks are layout work too; the stages
+    # inside carry scopes of their own)
+    @jax.named_scope("spfft.exchange_layout")
     def _overlap_bwd_to_grid(self, sticks_raw, onehot_row, col_inv, zmap,
                              ctables, pre_chunks=None):
         """Backward overlap pipeline: per chunk, run stick symmetry +
@@ -1312,6 +1353,7 @@ class DistributedTransformPlan:
         return grid_flat.reshape(batch + (dp.max_planes, dp.dim_y,
                                           self._xf_eff))
 
+    @jax.named_scope("spfft.exchange_layout")
     def _overlap_fwd_to_sticks(self, space, cols_flat, z_src, ctables):
         """Forward overlap pipeline (the backward's mirror): per chunk,
         xy-FFT the chunk's plane rows and issue its collective; one late
@@ -1379,9 +1421,10 @@ class DistributedTransformPlan:
             dec_il = self._pallas_gather(
                 values_il, self._pallas_dist["dec"],
                 ptables[:self._pallas_dist["n_dec"]])
-            flat = dec_il[..., 0] + 1j * dec_il[..., 1]
-            return flat.reshape(values_il.shape[:-2]
-                                + (dp.max_sticks, dp.dim_z))
+            with jax.named_scope("spfft.layout"):
+                flat = dec_il[..., 0] + 1j * dec_il[..., 1]
+                return flat.reshape(values_il.shape[:-2]
+                                    + (dp.max_sticks, dp.dim_z))
         dec = lambda v: stages.decompress(v.astype(self._rdt), slot_src[0],
                                           dp.max_sticks, dp.dim_z)
         if values_il.ndim == 3:
@@ -1400,9 +1443,11 @@ class DistributedTransformPlan:
             # Complete every stick, then blend by the one-hot (0,0)-stick
             # mask — SPMD-safe stand-in for the reference's "owner rank
             # applies StickSymmetry" branch (execution_host.cpp:306-308).
-            completed = jax.vmap(stages.complete_stick_hermitian)(sticks)
-            oh = onehot_row[:, None].astype(self._rdt)
-            sticks = sticks * (1 - oh) + completed * oh
+            with jax.named_scope("spfft.stick_symmetry"):
+                completed = jax.vmap(stages.complete_stick_hermitian)(
+                    sticks)
+                oh = onehot_row[:, None].astype(self._rdt)
+                sticks = sticks * (1 - oh) + completed * oh
         return stages.z_backward(sticks)
 
     def _bwd_post_exchange(self, grid):
@@ -1414,15 +1459,20 @@ class DistributedTransformPlan:
                 x0, _ = self._split_x
                 if x0 == 0:
                     grid = stages.complete_plane_hermitian(grid)
-                return stages.xy_backward_r2c_split(
-                    grid, x0, dp.dim_x, dp.dim_x_freq)
+                with jax.named_scope("spfft.xy"):
+                    return stages.xy_backward_r2c_split(
+                        grid, x0, dp.dim_x, dp.dim_x_freq)
             grid = stages.complete_plane_hermitian(grid)
-            return stages.xy_backward_r2c(grid, dp.dim_x)
-        if self._split_x is not None:
-            x0, _ = self._split_x
-            return complex_to_interleaved(
-                stages.xy_backward_c2c_split(grid, x0, dp.dim_x))
-        return complex_to_interleaved(stages.xy_backward_c2c(grid))
+            with jax.named_scope("spfft.xy"):
+                return stages.xy_backward_r2c(grid, dp.dim_x)
+        with jax.named_scope("spfft.xy"):
+            if self._split_x is not None:
+                x0, _ = self._split_x
+                space = stages.xy_backward_c2c_split(grid, x0, dp.dim_x)
+            else:
+                space = stages.xy_backward_c2c(grid)
+        with jax.named_scope("spfft.grid_io"):
+            return complex_to_interleaved(space)
 
     def _backward_tail(self, sticks, onehot, col_inv, zmap, ctables):
         """Per-shard pipeline after decompress: symmetry, z-IFFT, exchange,
@@ -1444,9 +1494,10 @@ class DistributedTransformPlan:
                        col_inv, zmap, z_src, conj_mult, *xtables):
         ptables = xtables[:self._n_ptables]
         ctables = xtables[self._n_ptables:self._n_ptables + self._n_ctables]
-        vals = values_il[0]
-        if self._has_conj:  # conjugate the folded hermitian mirrors
-            vals = vals * conj_mult[0]
+        with jax.named_scope("spfft.value_conj"):
+            vals = values_il[0]
+            if self._has_conj:  # conjugate the folded hermitian mirrors
+                vals = vals * conj_mult[0]
         if self._fused_dist is not None:
             # decompress + stick symmetry + z-IFFT in ONE kernel launch
             # per overlap chunk (one total for monolithic plans), each
@@ -1460,10 +1511,13 @@ class DistributedTransformPlan:
                 sticks_z = self._fused_dec_zdft_shard(vals, xtables)
                 grid = self._exchange_freq_to_grid(sticks_z, zmap,
                                                    col_inv, ctables)
-            return self._bwd_post_exchange(grid)[None]
-        sticks = self._decompress_shard(vals, slot_src, ptables)
-        return self._backward_tail(sticks, onehot, col_inv, zmap,
-                                   ctables)[None]
+            space = self._bwd_post_exchange(grid)
+        else:
+            sticks = self._decompress_shard(vals, slot_src, ptables)
+            space = self._backward_tail(sticks, onehot, col_inv, zmap,
+                                        ctables)
+        with jax.named_scope("spfft.grid_io"):
+            return space[None]
 
     def _backward_body_batched(self, values_il, vi, slot_src, onehot,
                                cols_flat, col_inv, zmap, z_src, conj_mult,
@@ -1476,9 +1530,20 @@ class DistributedTransformPlan:
         multi_transform_internal.hpp:47-94)."""
         ptables = xtables[:self._n_ptables]
         ctables = xtables[self._n_ptables:self._n_ptables + self._n_ctables]
-        vals_b = values_il[0]
-        if self._has_conj:  # (B, mv, 2) * (mv, 2) broadcasts over B
-            vals_b = vals_b * conj_mult[0]
+        with jax.named_scope("spfft.value_conj"):
+            vals_b = values_il[0]
+            if self._has_conj:  # (B, mv, 2) * (mv, 2) broadcasts over B
+                vals_b = vals_b * conj_mult[0]
+        space_b = self._backward_batched_tail(vals_b, slot_src, onehot,
+                                              col_inv, zmap, ptables,
+                                              ctables, xtables)
+        with jax.named_scope("spfft.grid_io"):
+            return space_b[None]
+
+    def _backward_batched_tail(self, vals_b, slot_src, onehot, col_inv,
+                               zmap, ptables, ctables, xtables):
+        """The batched backward after the value conjugation: (B, mv, 2)
+        values -> (B, planes, ...) slabs."""
         if self._fused_dist is not None:
             # one batched-grid fused launch per chunk covers decompress
             # + symmetry + z-IFFT for the whole batch
@@ -1497,7 +1562,7 @@ class DistributedTransformPlan:
                         lambda *cs: self._overlap_bwd_to_grid(
                             None, None, col_inv, zmap, ctables,
                             pre_chunks=cs))(*pre_b)
-                return jax.vmap(self._bwd_post_exchange)(grid_b)[None]
+                return jax.vmap(self._bwd_post_exchange)(grid_b)
             sticks_zb = self._fused_dec_zdft_shard(vals_b, xtables)
             if self._ragged is not None:
                 grid_b = self._exchange_freq_to_grid(sticks_zb, zmap,
@@ -1506,7 +1571,7 @@ class DistributedTransformPlan:
                 grid_b = jax.vmap(
                     lambda s: self._exchange_freq_to_grid(
                         s, zmap, col_inv, ctables))(sticks_zb)
-            return jax.vmap(self._bwd_post_exchange)(grid_b)[None]
+            return jax.vmap(self._bwd_post_exchange)(grid_b)
         sticks_b = self._decompress_shard(vals_b, slot_src, ptables)
         if self._overlap is not None and self._overlap.kind == "ragged":
             # chunk loop identical to the unbatched path; each chunk's
@@ -1514,7 +1579,7 @@ class DistributedTransformPlan:
             # (_overlap_bwd_to_grid is batch-aware for the ragged kind)
             grid_b = self._overlap_bwd_to_grid(sticks_b, onehot[0],
                                                col_inv, zmap, ctables)
-            return jax.vmap(self._bwd_post_exchange)(grid_b)[None]
+            return jax.vmap(self._bwd_post_exchange)(grid_b)
         if self._ragged is not None:
             # ragged_all_to_all has no vmap batching rule: vmap the
             # per-example halves, run ONE collective with the batch as a
@@ -1523,30 +1588,33 @@ class DistributedTransformPlan:
                 lambda s: self._bwd_pre_exchange(s, onehot[0]))(sticks_b)
             grid_b = self._exchange_freq_to_grid(s2, zmap, col_inv,
                                                  ctables)
-            return jax.vmap(self._bwd_post_exchange)(grid_b)[None]
+            return jax.vmap(self._bwd_post_exchange)(grid_b)
         # block/compact overlap flows through the vmapped per-example
         # tail (a2a/ppermute have batching rules), like the monolithic
         # non-ragged mechanisms
         return jax.vmap(
             lambda s: self._backward_tail(s, onehot, col_inv, zmap,
-                                          ctables))(sticks_b)[None]
+                                          ctables))(sticks_b)
 
     def _fwd_pre_exchange(self, space):
         """xy-FFT (the per-example half before the forward exchange)."""
         _faults.check_site("exchange.pack")  # trace time: per compile
         dp = self.dist_plan
-        if dp.hermitian:
+        with jax.named_scope("spfft.grid_io"):
+            if dp.hermitian:
+                grid = space.astype(self._rdt)
+            else:
+                grid = interleaved_to_complex(space).astype(self._cdt)
+        with jax.named_scope("spfft.xy"):
+            if dp.hermitian:
+                if self._split_x is not None:
+                    x0, w = self._split_x
+                    return stages.xy_forward_r2c_split(grid, x0, w)
+                return stages.xy_forward_r2c(grid)
             if self._split_x is not None:
                 x0, w = self._split_x
-                return stages.xy_forward_r2c_split(
-                    space.astype(self._rdt), x0, w)
-            return stages.xy_forward_r2c(space.astype(self._rdt))
-        if self._split_x is not None:
-            x0, w = self._split_x
-            return stages.xy_forward_c2c_split(
-                interleaved_to_complex(space).astype(self._cdt), x0, w)
-        return stages.xy_forward_c2c(
-            interleaved_to_complex(space).astype(self._cdt))
+                return stages.xy_forward_c2c_split(grid, x0, w)
+            return stages.xy_forward_c2c(grid)
 
     def _forward_head_raw(self, space, cols_flat, z_src, ctables):
         """Per-shard forward pipeline up to (not including) the z-stage:
@@ -1574,43 +1642,64 @@ class DistributedTransformPlan:
         scale = 1.0 / self.global_size if scaled else None
         batch = sticks.shape[:-2]
         # vi carries the sentinel max_sticks*dim_z for value padding
-        flat = jnp.stack([jnp.real(sticks).reshape(batch + (-1,)),
-                          jnp.imag(sticks).reshape(batch + (-1,))], axis=-1)
+        with jax.named_scope("spfft.layout"):
+            flat = jnp.stack([jnp.real(sticks).reshape(batch + (-1,)),
+                              jnp.imag(sticks).reshape(batch + (-1,))],
+                             axis=-1)
         if self._pallas_dist is not None:
             values = self._pallas_gather(
                 flat, self._pallas_dist["cmp"],
                 ptables[self._pallas_dist["n_dec"]:])
-        elif flat.ndim == 3:
-            values = jax.vmap(
-                lambda f: stages.gather_rows_with_sentinel(f, vi[0]))(flat)
         else:
-            values = stages.gather_rows_with_sentinel(flat, vi[0])
-        if scale is not None:
-            values = values * jnp.asarray(scale, self._rdt)
-        return values
+            with jax.named_scope("spfft.compress"):
+                if flat.ndim == 3:
+                    values = jax.vmap(
+                        lambda f: stages.gather_rows_with_sentinel(
+                            f, vi[0]))(flat)
+                else:
+                    values = stages.gather_rows_with_sentinel(flat, vi[0])
+        with jax.named_scope("spfft.compress"):
+            if scale is not None:
+                values = values * jnp.asarray(scale, self._rdt)
+            return values
 
     def _forward_body(self, space, vi, slot_src, onehot, cols_flat, col_inv,
                       zmap, z_src, conj_mult, *xtables, scaled: bool):
         ptables = xtables[:self._n_ptables]
         ctables = xtables[self._n_ptables:self._n_ptables + self._n_ctables]
+        with jax.named_scope("spfft.grid_io"):
+            slab = space[0]
         if self._fused_dist_fwd is not None:
             # post-exchange z-FFT + compress gather in ONE kernel launch
-            raw = self._forward_head_raw(space[0], cols_flat, z_src,
-                                         ctables)
+            raw = self._forward_head_raw(slab, cols_flat, z_src, ctables)
             values = self._fused_zdft_cmp_shard(raw, xtables, scaled)
         else:
-            sticks = self._forward_head(space[0], cols_flat, z_src,
-                                        ctables)
+            sticks = self._forward_head(slab, cols_flat, z_src, ctables)
             values = self._compress_shard(sticks, vi, ptables, scaled)
-        if self._has_conj:  # folded mirrors leave conjugated
-            values = values * conj_mult[0]
-        return values[None]
+        with jax.named_scope("spfft.value_conj"):
+            if self._has_conj:  # folded mirrors leave conjugated
+                values = values * conj_mult[0]
+            return values[None]
 
     def _forward_body_batched(self, space, vi, slot_src, onehot, cols_flat,
                               col_inv, zmap, z_src, conj_mult, *xtables,
                               scaled: bool):
         ptables = xtables[:self._n_ptables]
         ctables = xtables[self._n_ptables:self._n_ptables + self._n_ctables]
+        with jax.named_scope("spfft.grid_io"):
+            slab_b = space[0]
+        values_b = self._forward_batched_values(slab_b, vi, ptables,
+                                                cols_flat, z_src, ctables,
+                                                xtables, scaled)
+        with jax.named_scope("spfft.value_conj"):
+            if self._has_conj:
+                values_b = values_b * conj_mult[0]
+            return values_b[None]
+
+    def _forward_batched_values(self, slab_b, vi, ptables, cols_flat, z_src,
+                                ctables, xtables, scaled: bool):
+        """The batched forward up to the value conjugation: (B, planes,
+        ...) slabs -> (B, mv, 2) values."""
         if self._fused_dist_fwd is not None:
             # raw sticks assembled batched through the same exchange
             # structure as the unfused branches below, then ONE
@@ -1618,39 +1707,33 @@ class DistributedTransformPlan:
             # z_forward + the gather
             if (self._overlap is not None
                     and self._overlap.kind == "ragged"):
-                raw_b = self._overlap_fwd_to_sticks(space[0], cols_flat,
+                raw_b = self._overlap_fwd_to_sticks(slab_b, cols_flat,
                                                     z_src, ctables)
             elif self._ragged is not None:
-                grid_b = jax.vmap(self._fwd_pre_exchange)(space[0])
+                grid_b = jax.vmap(self._fwd_pre_exchange)(slab_b)
                 raw_b = self._exchange_grid_to_sticks(grid_b, cols_flat,
                                                       z_src, ctables)
             else:
                 raw_b = jax.vmap(
                     lambda s: self._forward_head_raw(
-                        s, cols_flat, z_src, ctables))(space[0])
-            values_b = self._fused_zdft_cmp_shard(raw_b, xtables, scaled)
-            if self._has_conj:
-                values_b = values_b * conj_mult[0]
-            return values_b[None]
+                        s, cols_flat, z_src, ctables))(slab_b)
+            return self._fused_zdft_cmp_shard(raw_b, xtables, scaled)
         if self._overlap is not None and self._overlap.kind == "ragged":
             # chunked forward with the batch on the collectives'
             # trailing dims (_overlap_fwd_to_sticks is batch-aware)
             sticks_b = stages.z_forward(self._overlap_fwd_to_sticks(
-                space[0], cols_flat, z_src, ctables))
+                slab_b, cols_flat, z_src, ctables))
         elif self._ragged is not None:
             # batch rides the collective's trailing dims (see
             # _backward_body_batched)
-            grid_b = jax.vmap(self._fwd_pre_exchange)(space[0])
+            grid_b = jax.vmap(self._fwd_pre_exchange)(slab_b)
             sticks_b = stages.z_forward(self._exchange_grid_to_sticks(
                 grid_b, cols_flat, z_src, ctables))
         else:
             sticks_b = jax.vmap(
                 lambda s: self._forward_head(s, cols_flat, z_src,
-                                             ctables))(space[0])
-        values_b = self._compress_shard(sticks_b, vi, ptables, scaled)
-        if self._has_conj:
-            values_b = values_b * conj_mult[0]
-        return values_b[None]
+                                             ctables))(slab_b)
+        return self._compress_shard(sticks_b, vi, ptables, scaled)
 
     def _pair_shmap(self, n_fn_args: int):
         """shard_map wrapper for the fused-pair entry points: base specs
@@ -1906,6 +1989,14 @@ class DistributedTransformPlan:
         errors.DistributedPlanUnsupportedError)."""
         leaves = jax.tree_util.tree_leaves(self._device_tables)
         return sum(int(getattr(leaf, "nbytes", 0)) for leaf in leaves)
+
+    def check_build(self, wait: bool = False) -> None:
+        """The local plan's readiness check. A distributed plan builds
+        every table in the foreground, so there is no builder to
+        surface; ``wait=True`` blocks until the tables committed at
+        plan time are on the devices."""
+        if wait:
+            jax.block_until_ready(self._device_tables)
 
     # -- data movement helpers ----------------------------------------------
     def shard_values(self, values_per_shard: Sequence) -> jax.Array:

@@ -40,7 +40,16 @@ import numpy as np
 from ..ops import stages
 from ..utils.dtypes import complex_to_interleaved, interleaved_to_complex
 
+#: Profiler scopes of the exchange (docs/observability.md): the collective
+#: itself, and the layout work around it (send blocks built, received
+#: blocks placed, wire casts). The innermost scope names an op, so every
+#: function below runs under the layout scope and each collective call
+#: under the exchange scope.
+_LAYOUT = "spfft.exchange_layout"
+_COLLECTIVE = "spfft.exchange"
 
+
+@jax.named_scope(_LAYOUT)
 def pack_freq_to_blocks(sticks, z_map):
     """Split z-FFT'ed local sticks into per-target-shard plane blocks.
 
@@ -55,6 +64,7 @@ def pack_freq_to_blocks(sticks, z_map):
     return jnp.transpose(blocks, (1, 0, 2))
 
 
+@jax.named_scope(_LAYOUT)
 def unpack_blocks_to_grid(blocks, global_col_inv, dim_y: int,
                           dim_x_freq: int):
     """Place received stick segments into the local frequency plane grid —
@@ -76,6 +86,7 @@ def unpack_blocks_to_grid(blocks, global_col_inv, dim_y: int,
     return grid_t.T.reshape(max_planes, dim_y, dim_x_freq)
 
 
+@jax.named_scope(_LAYOUT)
 def pack_space_to_blocks(grid, all_scatter_cols, num_shards: int,
                          max_sticks: int):
     """Forward-direction pack: gather every shard's stick columns out of the
@@ -95,6 +106,7 @@ def pack_space_to_blocks(grid, all_scatter_cols, num_shards: int,
     return jnp.transpose(blocks, (1, 2, 0))
 
 
+@jax.named_scope(_LAYOUT)
 def unpack_blocks_to_sticks(blocks, z_src):
     """Forward-direction unpack: reassemble full-z local sticks from received
     per-source-shard plane blocks (reference unpack_forward,
@@ -180,6 +192,7 @@ def dequantize_blocks_int8(packed, shape, quant_axis: int, real_dtype):
     return interleaved_to_complex(il.astype(real_dtype))
 
 
+@jax.named_scope(_LAYOUT)
 def ring_exchange_blocks(blocks, axis_name: str,
                          wire_real_dtype: Optional[jnp.dtype] = None,
                          quant_axis: int = 1):
@@ -214,7 +227,8 @@ def ring_exchange_blocks(blocks, axis_name: str,
     for k in range(1, num_shards):
         perm = [(j, (j + k) % num_shards) for j in range(num_shards)]
         send = blocks[(idx + k) % num_shards]
-        received.append(jax.lax.ppermute(send, axis_name, perm))
+        with jax.named_scope(_COLLECTIVE):
+            received.append(jax.lax.ppermute(send, axis_name, perm))
     stacked = jnp.stack(received, axis=0)
     # out[s] must be shard s's block = received[(r - s) % S]; as a function
     # of s that is a reversal followed by a roll of r + 1.
@@ -629,6 +643,7 @@ def build_ragged_schedule(dp, x_window=None) -> RaggedSchedule:
         emu_bwd=emu_bwd, emu_fwd=emu_fwd)
 
 
+@jax.named_scope(_LAYOUT)
 def ragged_exchange(buf, offsets, emu_table, recv_cap: int,
                     axis_name: str, emulate: bool,
                     wire_real_dtype: Optional[jnp.dtype] = None):
@@ -656,7 +671,8 @@ def ragged_exchange(buf, offsets, emu_table, recv_cap: int,
     if wire_real_dtype is not None:
         il = il.astype(wire_real_dtype)
     if emulate:
-        gathered = jax.lax.all_gather(il, axis_name)  # (S, [B,] cap, 2)
+        with jax.named_scope(_COLLECTIVE):
+            gathered = jax.lax.all_gather(il, axis_name)  # (S, [B,] cap, 2)
         flat = jnp.moveaxis(gathered, 1, 0).reshape(
             (il.shape[0],) + (-1, 2)) if batched \
             else gathered.reshape(-1, 2)
@@ -666,14 +682,16 @@ def ragged_exchange(buf, offsets, emu_table, recv_cap: int,
         io, ss, oo, rs = offsets
         op = jnp.moveaxis(il, 0, -2) if batched else il  # (cap, [B,] 2)
         out = jnp.zeros((recv_cap,) + op.shape[1:], op.dtype)
-        recv = jax.lax.ragged_all_to_all(op, out, io, ss, oo, rs,
-                                         axis_name=axis_name)
+        with jax.named_scope(_COLLECTIVE):
+            recv = jax.lax.ragged_all_to_all(op, out, io, ss, oo, rs,
+                                             axis_name=axis_name)
         if batched:
             recv = jnp.moveaxis(recv, -2, 0)  # (B, recv_cap, 2)
     recv = recv.astype(rdt)
     return recv[..., 0] + 1j * recv[..., 1]
 
 
+@jax.named_scope(_LAYOUT)
 def compact_exchange(bufs, ops, num_shards: int, axis_name: str,
                      reverse: bool,
                      wire_real_dtype: Optional[jnp.dtype] = None):
@@ -695,14 +713,17 @@ def compact_exchange(bufs, ops, num_shards: int, axis_name: str,
         if wire_real_dtype is not None:
             rdt = b.real.dtype
             il = complex_to_interleaved(b).astype(wire_real_dtype)
-            il = jax.lax.ppermute(il, axis_name, perm)
+            with jax.named_scope(_COLLECTIVE):
+                il = jax.lax.ppermute(il, axis_name, perm)
             b = interleaved_to_complex(il.astype(rdt))
         else:
-            b = jax.lax.ppermute(b, axis_name, perm)
+            with jax.named_scope(_COLLECTIVE):
+                b = jax.lax.ppermute(b, axis_name, perm)
         out.append(b)
     return jnp.concatenate(out) if len(out) > 1 else out[0]
 
 
+@jax.named_scope(_LAYOUT)
 def all_to_all_blocks(blocks, axis_name: str,
                       wire_real_dtype: Optional[jnp.dtype] = None,
                       quant_axis: int = 1):
@@ -718,17 +739,19 @@ def all_to_all_blocks(blocks, axis_name: str,
     packed alongside the payload (:func:`quantize_blocks_int8`) — still a
     single collective round.
     """
+    def collective(x):
+        with jax.named_scope(_COLLECTIVE):
+            return jax.lax.all_to_all(x, axis_name, split_axis=0,
+                                      concat_axis=0, tiled=True)
+
     if wire_real_dtype is None:
-        return jax.lax.all_to_all(blocks, axis_name, split_axis=0,
-                                  concat_axis=0, tiled=True)
+        return collective(blocks)
     if is_int8_wire(wire_real_dtype):
         rdt = blocks.real.dtype
         packed = quantize_blocks_int8(blocks, quant_axis)
-        out = jax.lax.all_to_all(packed, axis_name, split_axis=0,
-                                 concat_axis=0, tiled=True)
+        out = collective(packed)
         return dequantize_blocks_int8(out, blocks.shape, quant_axis, rdt)
     rdt = blocks.real.dtype
     il = complex_to_interleaved(blocks).astype(wire_real_dtype)
-    il = jnp.asarray(jax.lax.all_to_all(
-        il, axis_name, split_axis=0, concat_axis=0, tiled=True))
+    il = jnp.asarray(collective(il))
     return interleaved_to_complex(il.astype(rdt))

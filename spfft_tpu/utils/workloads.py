@@ -21,10 +21,9 @@ def round_robin_stick_partition(triplets: np.ndarray, dims,
     storage = np.where(triplets < 0,
                        triplets + np.asarray(dims, triplets.dtype), triplets)
     keys = storage[:, 0].astype(np.int64) * ny + storage[:, 1]
-    unique = np.unique(keys)
-    owner_of_key = {int(k): i % num_shards
-                    for i, k in enumerate(unique.tolist())}
-    owners = np.array([owner_of_key[int(k)] for k in keys])
+    # the i-th smallest stick key goes to shard i % num_shards
+    _, rank = np.unique(keys, return_inverse=True)
+    owners = rank.reshape(-1) % num_shards
     return [triplets[owners == r] for r in range(num_shards)]
 
 
