@@ -70,6 +70,7 @@ __all__ = [
     "compile_span",
     "record_compile", "record_plan_build", "record_exchange_plan",
     "record_hlo_counts", "record_plan_fallback", "record_fused_geometry",
+    "record_gather_geometry",
     "record_store",
     "record_store_aot_skip",
     # flight recorder (obs.recorder)
@@ -146,6 +147,18 @@ def record_fused_geometry(direction: str, geometry: str) -> None:
                         help="Fused z-kernel geometry chosen per plan "
                              "direction.",
                         direction=direction, geometry=geometry)
+
+
+def record_gather_geometry(plan: str, direction: str,
+                           geometry: str) -> None:
+    """The windowed-gather kernel geometry one compression direction of
+    a plan got (``wide`` or ``narrow``, ops/gather_kernel.py):
+    ``spfft_plan_gather_geometry_total`` by {plan=local|distributed,
+    direction=decompress|compress, geometry}."""
+    GLOBAL_COUNTERS.inc("spfft_plan_gather_geometry_total", 1,
+                        help="Windowed-gather geometry chosen per plan "
+                             "direction.",
+                        plan=plan, direction=direction, geometry=geometry)
 
 
 def _count_compile(kind: str, seconds: float) -> None:

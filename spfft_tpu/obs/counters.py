@@ -54,6 +54,11 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
          "Fused z-kernel geometry each fused plan direction got, "
          "labelled {direction=backward|forward, "
          "geometry=super_tile|narrow}."),
+    "spfft_plan_gather_geometry_total":
+        ("counter",
+         "Windowed-gather geometry each compression direction of a plan "
+         "got, labelled {plan=local|distributed, "
+         "direction=decompress|compress, geometry=wide|narrow}."),
     # distributed exchange accounting
     "spfft_exchange_plans_total":
         ("counter", "Distributed plans constructed."),

@@ -83,7 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .dft_kernel import _dot, _kara
 from . import gather_kernel as gk
-from .gather_kernel import (TILE, TILE_LANE, TILE_SUB,
+from .gather_kernel import (SMEM_TABLE_WORDS, TILE, TILE_LANE, TILE_SUB,
                             MonotoneGatherTables, _tile_compute_win,
                             _wide_tile_compute)
 
@@ -203,14 +203,6 @@ def _fits_forward(dim_z: int, win_sticks: int, step_tiles: int = 1) -> bool:
     out = 2 * 2 * step_tiles * TILE
     words = step_tiles * TILE
     return (mats + window + work + out + words) * 4 <= _VMEM_BUDGET
-
-
-#: Scalar-prefetch budget of the super-tile kernels, in int32 words. The
-#: tables are 1-D (a 2-D table pads each row to 512 bytes of the 1 MiB
-#: SMEM, gather_kernel.WIDE_SEG_CHUNK_LIMIT): ``4 + p_tiles`` words a
-#: grid step backward, ``5 + p_tiles`` forward. 768 KiB leaves the rest
-#: of SMEM to the compiler, as the narrow kernel's 70k-chunk cap does.
-SMEM_TABLE_WORDS = 196_608
 
 
 # -- plan-time tables --------------------------------------------------------

@@ -228,15 +228,24 @@ WIDE_P = 8
 #: spans ~ 8/fill_fraction.
 WIDE_KP_CANDIDATES = (8, 12, 16, 24, 32)
 
-#: Max chunks per wide launch: an SMEM budget. The scalar-prefetched
-#: tables live in the 1 MiB SMEM, where the (C, P//4) ``sub`` table takes
-#: 512 bytes a row, so C=2000 exceeds it ("Ran out of memory in memory
-#: space smem") while C=1536 compiles (tests/test_tpu_compile.py::
-#: test_wide_launch_at_chunk_limit); the narrow kernel's 1-D tables
-#: compile at C=17k+. Larger tables run as multiple tile-aligned launches
-#: (one extra launch per ~12.6M output slots — negligible next to the
-#: per-step win).
-WIDE_SEG_CHUNK_LIMIT = 1536
+#: Scalar-prefetch budget of one launch's 1-D SMEM tables, in int32
+#: words, shared by the wide gather and the fused super-tile kernels
+#: (ops.fused_kernel). A 2-D table pads each row to 512 bytes of the
+#: 1 MiB SMEM (a (C, 2) table overflowed it at C=2000); 1-D tables take
+#: their words. 768 KiB leaves the rest of SMEM to the compiler, as the
+#: narrow kernel's 70k-chunk cap does.
+SMEM_TABLE_WORDS = 196_608
+
+
+def wide_chunk_limit(p_tiles: int = WIDE_P) -> int:
+    """Most chunks one wide launch holds: its scalar-prefetch tables
+    (``row0``, ``out_tile``, ``first`` and the 1-D ``sub``) take
+    ``3 + p_tiles // 4`` words a chunk of :data:`SMEM_TABLE_WORDS` —
+    39,321 at P=8 (tests/test_tpu_compile.py::
+    test_wide_launch_at_chunk_limit). Larger tables run as several
+    tile-aligned launches."""
+    return SMEM_TABLE_WORDS // (3 + p_tiles // 4)
+
 
 #: Wide fallback ceiling, in chunks per super-tile: per-slot unit cost of
 #: the wide step is C*(P*kp + 96)/(G_s*P*TILE); the XLA gather breaks even
@@ -257,7 +266,8 @@ class WideGatherTables:
 
     row0: np.ndarray      # (C,) int32 — DMA window start row
     sub: np.ndarray       # (C, P//4) int32 — per-tile sub-window offsets,
-                          # byte-packed little-endian, relative to row0
+                          # byte-packed little-endian, relative to row0;
+                          # the device table is its 1-D (C * P//4,) form
     out_tile: np.ndarray  # (C,) int32 — output super-tile index
     first: np.ndarray     # (C,) int32 — 1 on a super-tile's first chunk
     packed: np.ndarray    # (C, P*8, 128) int16 — lane | row-in-sub << 7
@@ -413,7 +423,7 @@ def build_wide_gather_tables(idx: np.ndarray, valid: np.ndarray,
         words[:, j // 4] |= sub_o[:, j].astype(np.int32) << (8 * (j % 4))
 
     src_rows = max(int(row0.max()) + K, -(-int(num_src) // TILE_LANE))
-    segs = _tile_aligned_segments(first, st_o, G_s, WIDE_SEG_CHUNK_LIMIT)
+    segs = _tile_aligned_segments(first, st_o, G_s, wide_chunk_limit(P))
     if segs is None or (segs and not allow_segments):
         return None
     return WideGatherTables(
@@ -447,8 +457,7 @@ def _native_wide_tables(idx, valid, num_src, P, kp_rows, k_rows,
     L = int(np.asarray(idx).shape[0])
     G_s = -(-L // (P * TILE))
     src_rows = max(int(max_row0) + K, -(-int(num_src) // TILE_LANE))
-    segs = _tile_aligned_segments(first, out_tile, G_s,
-                                  WIDE_SEG_CHUNK_LIMIT)
+    segs = _tile_aligned_segments(first, out_tile, G_s, wide_chunk_limit(P))
     if segs is None or (segs and not allow_segments):
         return "blowup"
     return WideGatherTables(
@@ -859,15 +868,17 @@ def pad_wide_tables_to(t: WideGatherTables, c_max: int):
     Callers pass ``num_super + 1`` to :func:`wide_gather` and rely on the
     flat real-output prefix being unchanged (the dummy block is last).
 
-    Returns (row0, sub, out_tile, first, packed) padded to c_max rows."""
+    Returns the device tables (row0, sub, out_tile, first, packed) padded
+    to c_max chunks, ``sub`` in its 1-D kernel form."""
     pad = c_max - t.row0.shape[0]
     if pad < 0:
         raise ValueError("c_max smaller than existing chunk count")
     if pad == 0:
-        return t.row0, t.sub, t.out_tile, t.first, t.packed
+        return t.row0, t.sub.reshape(-1), t.out_tile, t.first, t.packed
     P = t.p_tiles
     row0 = np.concatenate([t.row0, np.zeros(pad, np.int32)])
-    sub = np.concatenate([t.sub, np.zeros((pad, P // 4), np.int32)])
+    sub = np.concatenate([t.sub, np.zeros((pad, P // 4), np.int32)]
+                         ).reshape(-1)
     out_tile = np.concatenate(
         [t.out_tile, np.full(pad, t.num_super, np.int32)])
     first = np.concatenate(
@@ -901,10 +912,11 @@ def _wide_step(kp: int, P: int, sub_ref, g, packed_blk, sc, slot, write):
     packed sub-window offset, slice its (kp, 128) sub-window out of the
     DMA'd window, compute, and hand (p, acc_re, acc_im) to ``write`` for
     the output store. The int16 packed block is loaded and widened ONCE
-    per step; per-tile rows are register slices."""
+    per step; per-tile rows are register slices. ``sub_ref`` is the 1-D
+    (C * P//4,) table: chunk ``g``'s words start at ``g * (P // 4)``."""
     t_all = packed_blk[...].astype(jnp.int32)        # (P*8, 128)
     for p in range(P):
-        word = sub_ref[g, p // 4]
+        word = sub_ref[g * (P // 4) + p // 4]
         sub = (word >> (8 * (p % 4))) & 0xFF
         win_re = sc[slot, 0, pl.ds(sub, kp), :]
         win_im = sc[slot, 1, pl.ds(sub, kp), :]
@@ -1020,16 +1032,19 @@ def wide_gather(re, im, row0, sub, out_tile, first, packed, *,
       re, im: (src_rows, 128) float32 planar source — or (B, src_rows, 128)
         batched.
       row0/sub/out_tile/first/packed: device tables (see
-        build_wide_gather_tables).
+        build_wide_gather_tables; ``sub`` 1-D, ``p_tiles // 4`` words a
+        chunk, as :func:`gather_device_tables` commits it).
     Returns:
       (out_re, out_im): each (num_super * p_tiles, 8, 128) float32, with a
       leading B when batched. Flat prefix = the num_out output slots.
     """
     if segs:
         outs_re, outs_im = [], []
+        w = p_tiles // 4  # sub words a chunk
         for (c0, c1, t0, t1) in segs:
             o_re, o_im = wide_gather(
-                re, im, row0[c0:c1], sub[c0:c1], out_tile[c0:c1] - t0,
+                re, im, row0[c0:c1], sub[c0 * w:c1 * w],
+                out_tile[c0:c1] - t0,
                 first[c0:c1], packed[c0:c1], span_rows=span_rows,
                 kp_rows=kp_rows, p_tiles=p_tiles, src_rows=src_rows,
                 num_super=t1 - t0, interpret=interpret)
@@ -1038,11 +1053,12 @@ def wide_gather(re, im, row0, sub, out_tile, first, packed, *,
         axis = 1 if re.ndim == 3 else 0
         return (jnp.concatenate(outs_re, axis=axis),
                 jnp.concatenate(outs_im, axis=axis))
-    if re.ndim == 3 and re.shape[0] * row0.shape[0] > WIDE_SEG_CHUNK_LIMIT:
+    if (re.ndim == 3
+            and re.shape[0] * row0.shape[0] > wide_chunk_limit(p_tiles)):
         # Big batches run as per-slab launches (loses cross-batch DMA
         # prefetch only). The split dates from a compiler that failed on
         # B * C grid steps; the installed one's limit is the C-row SMEM
-        # tables (WIDE_SEG_CHUNK_LIMIT), which a batch does not grow.
+        # tables (wide_chunk_limit), which a batch does not grow.
         outs = [_wide_gather_call(re[b], im[b], row0, sub, out_tile, first,
                                   packed, span_rows=span_rows,
                                   kp_rows=kp_rows, p_tiles=p_tiles,
@@ -1082,7 +1098,7 @@ def _wide_gather_call(re, im, row0, sub, out_tile, first, packed, *,
         kern = lambda *r: base(*r[:7], *r[9:])  # drop unused carry refs
     if re.ndim == 3:
         B = re.shape[0]
-        if B * C > WIDE_SEG_CHUNK_LIMIT:
+        if B * C > wide_chunk_limit(P):
             # big batches run per slab (see wide_gather)
             outs = [_wide_gather_call(
                 re[b], im[b], row0, sub, out_tile, first, packed,
@@ -1152,7 +1168,8 @@ def gather_device_tables(t) -> tuple:
     """Device-committed tables for either kind: a tuple of per-SEGMENT
     table tuples (one entry for unsegmented tables). Slicing happens here
     at plan time — slicing shared tables inside the jitted hot path costs
-    a 25 MB copy per execution (probe_r4_hlo)."""
+    a 25 MB copy per execution (probe_r4_hlo). The wide ``sub`` table
+    goes 1-D here (its 2-D form pads each row in SMEM)."""
     wide = isinstance(t, WideGatherTables)
     segs = t.segs if t.segs else ((0, t.row0.shape[0], 0,
                                    t.num_super if wide else t.num_tiles),)
@@ -1160,7 +1177,7 @@ def gather_device_tables(t) -> tuple:
     for (c0, c1, t0, t1) in segs:
         if wide:
             out.append((jnp.asarray(t.row0[c0:c1]),
-                        jnp.asarray(t.sub[c0:c1]),
+                        jnp.asarray(t.sub[c0:c1].reshape(-1)),
                         jnp.asarray(t.out_tile[c0:c1]),
                         jnp.asarray(t.first[c0:c1]),
                         jnp.asarray(t.packed[c0:c1])))

@@ -722,6 +722,7 @@ class DistributedTransformPlan:
         ``use_pallas=True`` on non-TPU builds tables but executes the XLA
         path (interpret mode per value-array would dominate local
         runtimes; here the SPMD body must be one program)."""
+        from .. import obs as _obs
         from ..ops import gather_kernel as gk
 
         dp = self.dist_plan
@@ -790,11 +791,14 @@ class DistributedTransformPlan:
         def build_all(which, num_src, num_out):
             # num_super / num_tiles are identical across shards already
             # (the idx length is the padded uniform max_values /
-            # max_sticks * dim_z on every shard). A wide cover takes at
-            # least one chunk a super-tile, and the stacked tables cannot
-            # split a launch: past the chunk limit it cannot fit.
+            # max_sticks * dim_z on every shard). The stacked tables
+            # cannot split a launch, so the wide cover is taken only
+            # where every shard's fits one (gk.wide_chunk_limit: the SMEM
+            # budget); it takes at least one chunk a super-tile, so past
+            # the limit in super-tiles it is not built at all. Else the
+            # narrow cover.
             supers = -(-num_out // (gk.WIDE_P * gk.TILE))
-            wide = supers <= gk.WIDE_SEG_CHUNK_LIMIT and build_uniform(
+            wide = supers <= gk.wide_chunk_limit() and build_uniform(
                 which, num_src, num_out, gk.build_wide_gather_tables,
                 gk.pad_wide_tables_to,
                 {"kp_rows": "kp_rows", "span_rows": "k_rows"},
@@ -824,6 +828,19 @@ class DistributedTransformPlan:
             "n_dec": len(dec["stacked"]),  # wide = 5 tables, narrow = 4
         }
         self._pallas_interpret = not backend_ok
+        for direction, geometry in self.gather_geometry.items():
+            _obs.record_gather_geometry("distributed", direction, geometry)
+
+    @property
+    def gather_geometry(self) -> dict:
+        """Windowed-gather kernel geometry of each compression direction
+        (``"decompress"`` / ``"compress"``): ``"wide"`` (one grid step
+        per super-tile of ``WIDE_P`` tiles) or ``"narrow"`` (one per
+        1024-slot tile); empty where the plan gathers with XLA."""
+        t = self._pallas_dist
+        if t is None:
+            return {}
+        return {"decompress": t["dec"]["kind"], "compress": t["cmp"]["kind"]}
 
     @jax.named_scope("spfft.gather")
     def _pallas_gather(self, flat_il, t, tables):

@@ -484,6 +484,13 @@ class TransformPlan:
                     if cmp_ is not None:
                         self._tables_hot["cmp_tabs"] = \
                             gk.gather_device_tables(cmp_)
+                for direction, t in (("decompress", dec),
+                                     ("compress", cmp_)):
+                    if t is not None:
+                        _obs.record_gather_geometry(
+                            "local", direction,
+                            "wide" if isinstance(t, gk.WideGatherTables)
+                            else "narrow")
                 if dec is None or cmp_ is None:
                     fell_back = [n for n, t in (("decompress", dec),
                                                 ("compress", cmp_))

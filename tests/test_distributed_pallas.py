@@ -229,3 +229,139 @@ def test_pallas_compact_float_split_r2c_combo():
     vmax = max(np.abs(np.concatenate(vals)).max(), 1.0)
     for g, v in zip(out, vals):
         np.testing.assert_allclose(g, v, atol=0.01 * vmax, rtol=0)
+
+
+# -- gather geometry: the wide cover in one launch a shard, else narrow ------
+
+GEOM_DIMS = (24, 24, 64)  # 144 sticks a shard: 9,216 slots, 2 super-tiles
+
+
+def _geometry_parts(scatter_first=False):
+    """Four stick-major shards of one random set; with ``scatter_first``
+    the first shard's sticks come in a random order (z still ascending
+    in each), so its wide cover needs more chunks than super-tiles."""
+    rng = np.random.default_rng(56)
+    triplets = random_sparse_triplets(rng, GEOM_DIMS, stick_fraction=1.0,
+                                      fill_fraction=0.9)
+    parts = [sort_triplets_stick_major(p, GEOM_DIMS)
+             for p in split_by_sticks(triplets, GEOM_DIMS, [1, 1, 1, 1])]
+    if scatter_first:
+        p0 = parts[0]
+        key = p0[:, 0] * GEOM_DIMS[1] + p0[:, 1]
+        sticks, inv = np.unique(key, return_inverse=True)
+        rank = np.random.default_rng(3).permutation(len(sticks))[inv]
+        parts[0] = p0[np.lexsort((p0[:, 2], rank))]
+    return parts, triplets
+
+
+def _geometry_plan(monkeypatch, chunks, scatter_first=False):
+    """A 4-shard C2C plan built with the wide launch's SMEM budget shrunk
+    to ``chunks`` chunks — the stand-in for a shard whose super-tile count
+    passes (or fits) the real budget, which only a 768^3 grid reaches."""
+    from spfft_tpu import obs
+    from spfft_tpu.ops import gather_kernel as gk
+    parts, triplets = _geometry_parts(scatter_first)
+    planes = split_planes(GEOM_DIMS[2], [1, 1, 1, 1])
+
+    def count(direction, geometry):
+        return obs.GLOBAL_COUNTERS.get("spfft_plan_gather_geometry_total",
+                                       plan="distributed",
+                                       direction=direction,
+                                       geometry=geometry)
+
+    dirs = [(d, g) for d in ("decompress", "compress")
+            for g in ("wide", "narrow")]
+    before = {k: count(*k) for k in dirs}
+    with monkeypatch.context() as m:
+        m.setattr(gk, "SMEM_TABLE_WORDS", chunks * (3 + gk.WIDE_P // 4))
+        plan = make_distributed_plan(
+            TransformType.C2C, *GEOM_DIMS, parts, planes, mesh=make_mesh(4),
+            precision="single", use_pallas=True)
+    counted = {k: count(*k) - before[k] for k in dirs}
+    return plan, parts, triplets, counted
+
+
+def test_wide_geometry_one_launch_a_shard(monkeypatch):
+    """Per-shard tables that fit one launch take the wide cover in both
+    directions (the counter and ``gather_geometry`` say so); where the
+    shard's super-tiles pass the limit, the narrow cover. The gather only
+    selects, so both give the same bits, and both match the dense
+    oracle."""
+    wide, parts, triplets, counted = _geometry_plan(monkeypatch, 2)
+    assert wide.gather_geometry == {"decompress": "wide",
+                                    "compress": "wide"}
+    assert counted == {("decompress", "wide"): 1, ("compress", "wide"): 1,
+                       ("decompress", "narrow"): 0,
+                       ("compress", "narrow"): 0}
+    for t in (wide._pallas_dist["dec"], wide._pallas_dist["cmp"]):
+        assert t["super_p1"] == 3  # 2 super-tiles a shard and the dummy
+        row0, sub = t["stacked"][:2]
+        assert sub.shape == (4, row0.shape[1] * t["p_tiles"] // 4)
+    narrow, _, _, counted = _geometry_plan(monkeypatch, 1)
+    assert narrow.gather_geometry == {"decompress": "narrow",
+                                      "compress": "narrow"}
+    assert counted == {("decompress", "narrow"): 1,
+                       ("compress", "narrow"): 1,
+                       ("decompress", "wide"): 0, ("compress", "wide"): 0}
+
+    rng = np.random.default_rng(57)
+    vals = [random_values(rng, len(p)).astype(np.complex64) for p in parts]
+    space_w = np.asarray(wide.backward(vals))
+    np.testing.assert_array_equal(space_w, np.asarray(narrow.backward(vals)))
+    slabs = wide.unshard_space(space_w)
+    cube = dense_cube_from_values(np.concatenate(parts),
+                                  np.concatenate(vals), GEOM_DIMS)
+    oracle = dense_backward(cube)
+    np.testing.assert_allclose(np.concatenate(slabs, axis=0), oracle,
+                               atol=tolerance_for("single", oracle), rtol=0)
+
+    fwd_w = np.asarray(wide.forward(slabs, Scaling.NONE))
+    np.testing.assert_array_equal(
+        fwd_w, np.asarray(narrow.forward(slabs, Scaling.NONE)))
+    freq = dense_forward(np.concatenate(slabs, axis=0).astype(np.complex128))
+    for got, p in zip(wide.unshard_values(fwd_w), parts):
+        want = sample_cube(freq, p, GEOM_DIMS)
+        np.testing.assert_allclose(got, want,
+                                   atol=tolerance_for("single", want),
+                                   rtol=0)
+
+
+def test_wide_cover_past_limit_falls_back_narrow(monkeypatch):
+    """A shard whose sticks are scattered covers its 2 super-tiles in more
+    than 4 wide chunks: under a 4-chunk budget the shard-uniform tables
+    pass the limit though the super-tile count fits, and both directions
+    fall back to narrow; under 8 they take one wide launch a shard.
+    Either cover selects the same values, bit for bit."""
+    narrow, parts, _, counted = _geometry_plan(monkeypatch, 4,
+                                               scatter_first=True)
+    assert narrow.gather_geometry == {"decompress": "narrow",
+                                      "compress": "narrow"}
+    assert counted[("decompress", "narrow")] == 1
+    assert counted[("compress", "narrow")] == 1
+    wide, _, _, counted = _geometry_plan(monkeypatch, 8, scatter_first=True)
+    assert wide.gather_geometry == {"decompress": "wide", "compress": "wide"}
+    assert counted[("decompress", "wide")] == 1
+    assert counted[("compress", "wide")] == 1
+    for t in (wide._pallas_dist["dec"], wide._pallas_dist["cmp"]):
+        assert t["super_p1"] == 3 and 4 < t["stacked"][0].shape[1] <= 8
+
+    rng = np.random.default_rng(59)
+    vals = [random_values(rng, len(p)).astype(np.complex64) for p in parts]
+    space = np.asarray(wide.backward(vals))
+    np.testing.assert_array_equal(space, np.asarray(narrow.backward(vals)))
+    slabs = wide.unshard_space(space)
+    np.testing.assert_array_equal(
+        np.asarray(wide.forward(slabs, Scaling.NONE)),
+        np.asarray(narrow.forward(slabs, Scaling.NONE)))
+
+
+def test_gather_geometry_empty_without_kernel():
+    """A plan on the XLA gather path reports no kernel geometry."""
+    rng = np.random.default_rng(58)
+    triplets = random_sparse_triplets(rng, DIMS)
+    parts = split_by_sticks(triplets, DIMS, [1, 1, 1, 1])
+    planes = split_planes(DIMS[2], [1, 1, 1, 1])
+    plan = make_distributed_plan(TransformType.C2C, *DIMS, parts, planes,
+                                 mesh=make_mesh(4), precision="single",
+                                 use_pallas=False)
+    assert plan.gather_geometry == {}

@@ -390,13 +390,19 @@ def test_wide_forced_geometry_rebuild():
 
 def test_wide_padded_tables_dummy_block():
     """pad_wide_tables_to appends no-op chunks targeting a dummy super-tile;
-    running with num_super + 1 leaves the real output prefix unchanged."""
+    running with num_super + 1 leaves the real output prefix unchanged.
+    Its ``sub`` is the kernel's 1-D table, ``P // 4`` words a chunk."""
     rng = np.random.default_rng(17)
     M = 40_000
     idx = np.sort(rng.choice(M, 20_000, replace=False)).astype(np.int64)
     src = rng.random((M, 2)).astype(np.float32)
     t = gk.build_wide_gather_tables(idx, np.ones(len(idx), bool), M)
-    padded = gk.pad_wide_tables_to(t, t.row0.shape[0] + 7)
+    C, w = t.row0.shape[0], t.p_tiles // 4
+    padded = gk.pad_wide_tables_to(t, C + 7)
+    assert padded[1].shape == ((C + 7) * w,)
+    np.testing.assert_array_equal(padded[1][:C * w], t.sub.reshape(-1))
+    assert not padded[1][C * w:].any()
+    assert gk.pad_wide_tables_to(t, C)[1].shape == (C * w,)
     re, im = gk.planar_from_interleaved(jnp.asarray(src, jnp.float32),
                                         t.src_rows)
     out_re, out_im = gk.wide_gather(
@@ -407,26 +413,56 @@ def test_wide_padded_tables_dummy_block():
     np.testing.assert_array_equal(np.asarray(got), src[idx])
 
 
-def test_wide_segments():
-    """Chunk counts past WIDE_SEG_CHUNK_LIMIT run as multiple tile-aligned
-    launches (the compile-crash workaround) with identical results."""
+def _limit_chunks(monkeypatch, chunks, p_tiles=gk.WIDE_P):
+    """Shrink the SMEM word budget so one wide launch holds ``chunks``."""
+    monkeypatch.setattr(gk, "SMEM_TABLE_WORDS", chunks * (3 + p_tiles // 4))
+    assert gk.wide_chunk_limit(p_tiles) == chunks
+
+
+def test_wide_chunk_limit_from_smem_budget():
+    """A wide launch's scalar-prefetch tables take ``3 + P // 4`` SMEM
+    words a chunk (row0, out_tile, first and the 1-D sub): 39,321
+    chunks at P=8 fit the budget, which holds the 768^3 shard's 6,913."""
+    assert gk.wide_chunk_limit() == gk.SMEM_TABLE_WORDS // 5 == 39_321
+    assert gk.wide_chunk_limit(16) == gk.SMEM_TABLE_WORDS // 7
+    assert gk.wide_chunk_limit() >= 6_913
+
+
+def test_wide_segments(monkeypatch):
+    """Chunk counts past the SMEM budget's chunk limit run as multiple
+    tile-aligned launches with identical results; each segment's device
+    ``sub`` is the 1-D slice of its chunks."""
     rng = np.random.default_rng(18)
     L = 12 * gk.WIDE_P * gk.TILE
     idx = np.arange(L, dtype=np.int64)
     src = rng.random((L, 2)).astype(np.float32)
-    old = gk.WIDE_SEG_CHUNK_LIMIT
-    gk.WIDE_SEG_CHUNK_LIMIT = 5
-    try:
+    with monkeypatch.context() as m:
+        _limit_chunks(m, 5)
         t = gk.build_wide_gather_tables(idx, np.ones(L, bool), L)
-    finally:
-        gk.WIDE_SEG_CHUNK_LIMIT = old
     assert t is not None and len(t.segs) >= 2
+    assert all(c1 - c0 <= 5 for c0, c1, _, _ in t.segs)
+    w = t.p_tiles // 4
+    for (c0, c1, _, _), tabs in zip(t.segs, gk.gather_device_tables(t)):
+        np.testing.assert_array_equal(np.asarray(tabs[1]),
+                                      t.sub[c0:c1].reshape(-1))
+        assert tabs[1].shape == ((c1 - c0) * w,)
     out = np.asarray(gk.run_gather_values(jnp.asarray(src, jnp.float32), t,
                                           interpret=True))
     np.testing.assert_array_equal(out, src)
+    # the jitted entry slices the 1-D sub by chunk too
+    re, im = gk.planar_from_interleaved(jnp.asarray(src, jnp.float32),
+                                        t.src_rows)
+    out_re, out_im = gk.wide_gather(
+        re, im, jnp.asarray(t.row0), jnp.asarray(t.sub.reshape(-1)),
+        jnp.asarray(t.out_tile), jnp.asarray(t.first),
+        jnp.asarray(t.packed), span_rows=t.span_rows, kp_rows=t.kp_rows,
+        p_tiles=t.p_tiles, src_rows=t.src_rows, num_super=t.num_super,
+        interpret=True, segs=t.segs)
+    got = gk.interleaved_from_planar(out_re, out_im, t.num_out)
+    np.testing.assert_array_equal(np.asarray(got), src)
 
 
-def test_wide_batched_split_over_step_budget():
+def test_wide_batched_split_over_step_budget(monkeypatch):
     """A batched launch whose B*C exceeds the chunk limit splits into
     per-slab launches (total-grid-step compile-crash guard)."""
     rng = np.random.default_rng(19)
@@ -436,12 +472,9 @@ def test_wide_batched_split_over_step_budget():
     t = gk.build_wide_gather_tables(idx, np.ones(L, bool), L)
     assert t is not None
     re, im = gk.planar_from_interleaved(jnp.asarray(src), t.src_rows)
-    old = gk.WIDE_SEG_CHUNK_LIMIT
-    gk.WIDE_SEG_CHUNK_LIMIT = 2 * t.row0.shape[0]  # B=3 crosses, C alone not
-    try:
+    with monkeypatch.context() as m:
+        _limit_chunks(m, 2 * t.row0.shape[0])  # B=3 crosses, C alone not
         out_re, out_im = gk.run_gather(re, im, gk.gather_device_tables(t),
                                        t, interpret=True)
-    finally:
-        gk.WIDE_SEG_CHUNK_LIMIT = old
     got = np.asarray(gk.interleaved_from_planar(out_re, out_im, t.num_out))
     np.testing.assert_array_equal(got, src)

@@ -192,6 +192,22 @@ def _check_wide_parity(monkeypatch, p_tiles):
             a, b = getattr(t_nat, field), getattr(t_py, field)
             assert a.dtype == b.dtype, f"{name}.{field} dtype"
             np.testing.assert_array_equal(a, b, err_msg=f"{name}.{field}")
+        # the kernel's 1-D sub, as both builds commit it: chunk g's
+        # byte-packed words at g * (P // 4)
+        C, w = t_nat.row0.shape[0], p_tiles // 4
+        assert t_nat.sub.shape == t_py.sub.shape == (C, w), name
+        for dn, dp in zip(gk.gather_device_tables(t_nat),
+                          gk.gather_device_tables(t_py)):
+            for a, b in zip(dn, dp):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+        flat = np.concatenate([np.asarray(d[1])
+                               for d in gk.gather_device_tables(t_nat)])
+        assert flat.shape == (C * w,), name
+        g, j = np.arange(C)[:, None], np.arange(p_tiles)
+        np.testing.assert_array_equal(
+            (flat[g * w + j // 4] >> (8 * (j % 4))) & 0xFF,
+            (t_py.sub[:, j // 4] >> (8 * (j % 4))) & 0xFF, err_msg=name)
 
 
 def test_native_wide_tables_blowup_parity(monkeypatch):

@@ -223,9 +223,8 @@ def _gather_inputs(plan):
 @pytest.mark.parametrize("kind", ["narrow", "wide"])
 @pytest.mark.parametrize("direction", ["dec", "cmp"])
 def test_gather_kernel_256_compiles(plans, compile_for, kind, direction):
-    """``run_gather`` on the 256^3 C2C plan's real compression tables;
-    wide tables run as several launches of at most
-    ``WIDE_SEG_CHUNK_LIMIT`` chunks, chained by output aliasing."""
+    """``run_gather`` on the 256^3 C2C plan's real compression tables,
+    each direction one launch (``gk.wide_chunk_limit`` holds them)."""
     plan = plans["c2c"]
     num_slots, ((dec_idx, occ), (cmp_idx, cmp_valid)) = \
         _gather_inputs(plan)
@@ -235,7 +234,7 @@ def test_gather_kernel_256_compiles(plans, compile_for, kind, direction):
     build = (gk.build_wide_gather_tables if kind == "wide"
              else gk.build_monotone_gather_tables)
     t = build(idx, valid, num_src)
-    assert t is not None
+    assert t is not None and not t.segs
     src = np.zeros((t.src_rows, gk.TILE_LANE), np.float32)
     dev = jax.tree_util.tree_map(np.asarray, gk.gather_device_tables(t))
     text = compile_for(lambda re, im, d: gk.run_gather(re, im, d, t),
@@ -311,21 +310,77 @@ def test_xy_kernel_compiles(as_tpu, compile_for, n, planes):
     assert "tpu_custom_call" in compile_for(xy, x, x, m, m)
 
 
-def test_wide_launch_at_chunk_limit(compile_for):
-    """One wide launch of ``WIDE_SEG_CHUNK_LIMIT`` chunks compiles; the
-    cap exists because the scalar-prefetched tables live in the 1 MiB
-    SMEM, where the (C, P//4) ``sub`` table takes 512 bytes a row."""
-    C, P = gk.WIDE_SEG_CHUNK_LIMIT, gk.WIDE_P
-    src = np.zeros((70000, gk.TILE_LANE), np.float32)
-    i32 = functools.partial(np.zeros, dtype=np.int32)
-    tabs = (i32(C), i32((C, P // 4)), i32(C), i32(C),
-            np.zeros((C, P * 8, gk.TILE_LANE), np.int16))
+@pytest.mark.parametrize("chunks", ["limit", 6_913])
+def test_wide_launch_at_chunk_limit(one_chip, compile_for, chunks):
+    """One wide launch compiles at ``wide_chunk_limit()`` chunks, and at
+    6,913: the 768^3 C2C shard's 6,912 super-tiles (73,728 full sticks a
+    chip) and the padding one. Its scalar-prefetch tables are 1-D and
+    take ``3 + P // 4`` words a chunk of ``SMEM_TABLE_WORDS``, 768 KiB of
+    the 1 MiB SMEM (70,000 chunks overflow it); a 2-D ``sub`` table
+    padded each row to 512 bytes and held a launch to 1,536 chunks."""
+    P = gk.WIDE_P
+    C = gk.wide_chunk_limit(P) if chunks == "limit" else chunks
+    assert C * (3 + P // 4) <= gk.SMEM_TABLE_WORDS
+
+    def shape(*dims, dtype=np.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    src = shape(70000, gk.TILE_LANE, dtype=np.float32)
+    tabs = (shape(C), shape(C * P // 4), shape(C), shape(C),
+            shape(C, P * 8, gk.TILE_LANE, dtype=np.int16))
     text = compile_for(
         lambda re, im, *t: gk._wide_gather_call(
             re, im, *t, span_rows=64, kp_rows=16, p_tiles=P, num_super=C,
             interpret=False),
         src, src, *tabs)
     assert "tpu_custom_call" in text
+
+
+def test_distributed_wide_gather_one_launch(topo, as_tpu, compile_for,
+                                            monkeypatch):
+    """The distributed plan's compression gathers, compiled for four
+    described chips: each direction is one ``wide_gather`` launch a chip
+    (the shard-uniform wide tables, 1-D ``sub`` included, in one SPMD
+    program), and no narrow ``monotone_gather``. SpFFT's C2C cutoff set
+    at 64^3 and sparsity 0.5, round-robin over 4 shards: 4 super-tiles a
+    shard. The plan's tables are shapes, never arrays on a chip."""
+    import re
+    from jax.sharding import Mesh
+    from benchmark.sticks.cutoff import cutoff_triplets
+    from spfft_tpu.parallel import make_distributed_plan
+    from spfft_tpu.parallel.mesh import SHARD_AXIS
+    from spfft_tpu.utils.workloads import round_robin_stick_partition
+
+    def put(x, sharding=None, *args, **kw):
+        return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                    sharding=sharding)
+
+    n = 64
+    triplets = cutoff_triplets((n, n, n), 0.5, False)
+    parts = round_robin_stick_partition(triplets, (n, n, n), 4)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_put", put)
+        plan = make_distributed_plan(
+            TransformType.C2C, n, n, n, parts, [n // 4] * 4,
+            mesh=Mesh(np.asarray(topo.devices[:4]), (SHARD_AXIS,)),
+            precision="single", use_pallas=True)
+    assert plan.gather_geometry == {"decompress": "wide", "compress": "wide"}
+    assert plan._pallas_dist["dec"]["super_p1"] == 4 + 1
+    dp, tabs = plan.dist_plan, plan._device_tables
+    v = jax.ShapeDtypeStruct((4, dp.max_values, 2), np.float32,
+                             sharding=plan._sharded)
+    space = jax.eval_shape(plan._backward_jit, v, *tabs)
+    space = jax.ShapeDtypeStruct(space.shape, space.dtype,
+                                 sharding=plan._sharded)
+    for jitted, arg in ((plan._backward_jit, v),
+                        (plan._forward_jit[Scaling.FULL], space)):
+        text = jitted.lower(arg, *tabs).compile().as_text()
+        kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+                   for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        gathers = [k for k in kernels if "spfft.gather" in k]
+        assert len(gathers) == 1 and "jit(wide_gather)" in gathers[0]
+        assert "monotone_gather" not in text
 
 
 @pytest.fixture(scope="module")

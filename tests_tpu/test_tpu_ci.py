@@ -118,7 +118,7 @@ def test_segmented_aliased_carry_accumulate(monkeypatch):
     # limit 2 at 32^3 segments BOTH table kinds (measured: decompress =
     # wide kernel, 2 segments; compress = narrow kernel, 14 segments)
     monkeypatch.setattr(gk, "SEG_CHUNK_LIMIT", 2)
-    monkeypatch.setattr(gk, "WIDE_SEG_CHUNK_LIMIT", 2)
+    monkeypatch.setattr(gk, "SMEM_TABLE_WORDS", 2 * (3 + gk.WIDE_P // 4))
     n = 32
     tr = spherical_cutoff_triplets(n)
     plan = make_local_plan(TransformType.C2C, n, n, n, tr,
